@@ -15,31 +15,31 @@ bench:               ## full benchmark suite (paper figures)
 	python -m benchmarks.run
 
 bench-paged:         ## paged KV arena vs dense merge vs sync data planes
-	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} PYTHONHASHSEED=0 \
+	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} \
 	REPRO_BENCH_SECTION=live,sim python -m benchmarks.continuous_batching
 
 bench-chunked:       ## chunked vs unchunked prefill (head-of-line stall)
-	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} PYTHONHASHSEED=0 \
+	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} \
 	REPRO_BENCH_SECTION=chunked python -m benchmarks.continuous_batching
 
 bench-prefix:        ## radix prefix cache vs cold prefill (token reuse)
-	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} PYTHONHASHSEED=0 \
+	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} \
 	REPRO_BENCH_SECTION=prefix python -m benchmarks.continuous_batching
 
 bench-decode:        ## zero-gather paged decode vs dense-gather oracle
-	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} PYTHONHASHSEED=0 \
+	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} \
 	REPRO_BENCH_SECTION=decode python -m benchmarks.continuous_batching
 
 bench-spec:          ## speculative decode vs oracle (accepted/launch gate)
-	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} PYTHONHASHSEED=0 \
+	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} \
 	REPRO_BENCH_SECTION=spec python -m benchmarks.continuous_batching
 
 bench-goodput:       ## sdf admission + parking preemption vs fifo
-	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} PYTHONHASHSEED=0 \
+	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} \
 	REPRO_BENCH_SECTION=goodput python -m benchmarks.continuous_batching
 
 bench-chaos:         ## crash-mid-burst recovery vs failure-free oracle
-	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} PYTHONHASHSEED=0 \
+	REPRO_BENCH_SMOKE=$${REPRO_BENCH_SMOKE:-0} \
 	REPRO_BENCH_SECTION=chaos python -m benchmarks.continuous_batching
 
 serve:               ## end-to-end serving driver

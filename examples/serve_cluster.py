@@ -10,14 +10,13 @@ the frequency service and sticky DP routing for the stateful SSM.
 import argparse
 import time
 
-import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.core import (EdgeCloudControlPlane, Outcome, Request, ServerSpec,
                         ServiceSpec, Sensitivity)
 from repro.core.faults import FaultEvent, FaultInjector, FaultSpec
-from repro.models.registry import model_api
+from repro.launch.serve import init_params
 from repro.serving.engine import (EparaServingEngine, GenerationRequest,
                                   ServiceRuntime)
 from repro.serving.failover import ClusterSupervisor, RetryPolicy
@@ -81,8 +80,7 @@ def main():
         if sid < 0:
             continue
         cfg = cfgs[svc]
-        params = model_api(cfg).init(
-            jax.random.PRNGKey(abs(hash(svc)) % 2**31), cfg)
+        params = init_params(cfg, seed=0)
         engines[sid].deploy(svc, ServiceRuntime(cfg, params, cp.plans[svc],
                                                 tracer=tracer,
                                                 metrics=metrics))

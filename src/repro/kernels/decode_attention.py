@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 from .ref import NEG_INF
 
 DEFAULT_KV_BLOCK = 512
@@ -138,7 +136,7 @@ def decode_attention_pallas(q, k_cache, v_cache, cache_len, *, window=None,
             pltpu.VMEM((_SUB, 128), jnp.float32),
             pltpu.VMEM((_SUB, D), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qt, kt, vt)
@@ -264,7 +262,7 @@ def chunk_prefill_attention_pallas(q, k_cache, v_cache, start, chunk_len, *,
             pltpu.VMEM((Tp, 128), jnp.float32),
             pltpu.VMEM((Tp, D), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(starts, ends, qt, kt, vt)
@@ -377,7 +375,7 @@ def paged_chunk_prefill_attention_pallas(q, k_pages, v_pages, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, Tp, D), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, start, start + chunk_len, qt, kp, vp)
@@ -515,7 +513,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, _SUB, D), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, cache_len, qt, kp, vp)
@@ -633,7 +631,7 @@ def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, _SUB, D), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, cache_len, qt, kp, vp, ks, vs)
@@ -736,7 +734,7 @@ def paged_chunk_prefill_attention_quant_pallas(q, k_pages, v_pages,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, Tp, D), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, start, start + chunk_len, qt, kp, vp, ks, vs)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 from .ref import NEG_INF
 
 DEFAULT_Q_BLOCK = 128
@@ -102,7 +100,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         m = m_ref[...][:, :1]
         lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-37)),
                         -NEG_INF)
-        lse_ref[0] = lse[:, 0]
+        lse_ref[0] = lse
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=None, prefix_len=0,
@@ -153,18 +151,20 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, prefix_len=0,
         out_specs=[
             pl.BlockSpec((1, q_block, D),
                          lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, q_block), lambda bh, qi, ki: (bh, qi)),
+            # a trailing unit axis keeps the block's last two dims
+            # (q_block, 1) within the TPU's (8, 128) tiling rule
+            pl.BlockSpec((1, q_block, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hq, Lq_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B * Hq, Lq_p), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, Lq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((q_block, _LANES), jnp.float32),   # m
             pltpu.VMEM((q_block, _LANES), jnp.float32),   # l
             pltpu.VMEM((q_block, D), jnp.float32),        # acc
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)

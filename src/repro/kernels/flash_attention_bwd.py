@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 from .ref import NEG_INF
 
 DEFAULT_BLOCK = 128
@@ -214,7 +212,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal=True,
                                lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Lq_p, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((q_block, D), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt, dot_, lse_t, dlt_t)
@@ -250,7 +248,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal=True,
         ],
         scratch_shapes=[pltpu.VMEM((k_block, D), jnp.float32),
                         pltpu.VMEM((k_block, D), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt, dot_, lse_t, dlt_t)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 DEFAULT_BLOCK_C = 128
 DEFAULT_BLOCK_N = 128
 DEFAULT_BLOCK_K = 512
@@ -70,7 +68,7 @@ def grouped_matmul_pallas(lhs, rhs, *, block_c=DEFAULT_BLOCK_C,
                                lambda e, ci, ni, ki: (e, ci, ni)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, Np), lhs.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_n), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,21 +1,25 @@
 """Jit-friendly wrappers over the Pallas kernels and their jnp oracles.
 
 Every op takes ``impl``:
-  * ``"ref"``               — memory-bounded pure-jnp path (XLA). Default on
-                              CPU and for the compiled multi-pod dry-run.
+  * ``"ref"``               — memory-bounded pure-jnp path (XLA): the CPU
+                              path, and the compiled multi-pod dry-run.
   * ``"pallas"``            — the TPU kernel (deployment target).
   * ``"pallas_interpret"``  — the TPU kernel body interpreted on CPU; used
                               by tests to validate kernels vs the oracles.
 
-``default_impl()`` reads REPRO_KERNEL_IMPL, falling back to "ref" so the
-whole framework runs anywhere; on a TPU runtime set REPRO_KERNEL_IMPL=pallas.
+``default_impl()`` resolves the backend's path: ``pallas`` on a TPU,
+``ref`` elsewhere.  REPRO_KERNEL_IMPL forces a value, but ``pallas`` off a
+TPU is an error — it never degrades to interpret mode.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import ref
 from .decode_attention import (chunk_prefill_attention_pallas,
@@ -34,15 +38,44 @@ VALID_IMPLS = ("ref", "pallas", "pallas_interpret")
 
 
 def default_impl() -> str:
-    impl = os.environ.get("REPRO_KERNEL_IMPL", "ref")
+    backend = jax.default_backend()
+    impl = os.environ.get("REPRO_KERNEL_IMPL")
+    if impl is None:
+        return "pallas" if backend == "tpu" else "ref"
     if impl not in VALID_IMPLS:
         raise ValueError(f"REPRO_KERNEL_IMPL={impl!r}; want one of {VALID_IMPLS}")
+    if impl == "pallas" and backend != "tpu":
+        raise ValueError(
+            f"REPRO_KERNEL_IMPL=pallas needs a TPU backend, got {backend!r} "
+            f"(use pallas_interpret to run the kernel bodies on {backend})")
     return impl
 
 
-import functools
+def _split_heads(kernel, head_axes, out_head_axis: int):
+    """``kernel`` run once per shard of the ambient mesh's ``model`` axis.
 
-import jax
+    XLA cannot partition a Mosaic kernel, so under a model-parallel mesh
+    (``jax.set_mesh``) each operand's head axis (``None`` = replicated) is
+    split with ``shard_map`` and every device attends over its own heads —
+    attention never mixes heads.  Without such a mesh the kernel runs as
+    is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or dict(mesh.shape).get("model", 1) == 1:
+        return kernel
+
+    def spec(ax, ndim):
+        return P(*("model" if d == ax else None for d in range(ndim)))
+
+    def call(*args):
+        args = [jnp.asarray(a) for a in args]
+        in_specs = tuple(P() if ax is None else spec(ax, jnp.ndim(a))
+                         for a, ax in zip(args, head_axes))
+        out_nd = jnp.ndim(args[0])
+        return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                             out_specs=spec(out_head_axis, out_nd),
+                             check_vma=False)(*args)
+
+    return call
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -136,11 +169,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len, *,
                            paged_gather_ref(v_pages.scales, bt))
             return ref.decode_attention_ref(q, k, v, cache_len,
                                             softmax_scale=softmax_scale)
-        return paged_decode_attention_quant_pallas(
+        kernel = functools.partial(paged_decode_attention_quant_pallas,
+                                   softmax_scale=softmax_scale,
+                                   interpret=(impl == "pallas_interpret"))
+        return _split_heads(kernel, (1, 2, 2, 2, 2, None, None), 1)(
             q, k_pages.values, v_pages.values, k_pages.scales,
-            v_pages.scales, block_tables, cache_len,
-            softmax_scale=softmax_scale,
-            interpret=(impl == "pallas_interpret"))
+            v_pages.scales, block_tables, cache_len)
     if impl == "ref":
         bs, trash = k_pages.shape[1], k_pages.shape[0] - 1
         bt = mask_block_tables(block_tables, cache_len, bs, trash)
@@ -148,9 +182,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len, *,
         v = paged_gather_ref(v_pages, bt)
         return ref.decode_attention_ref(q, k, v, cache_len,
                                         softmax_scale=softmax_scale)
-    return paged_decode_attention_pallas(
-        q, k_pages, v_pages, block_tables, cache_len,
-        softmax_scale=softmax_scale, interpret=(impl == "pallas_interpret"))
+    kernel = functools.partial(paged_decode_attention_pallas,
+                               softmax_scale=softmax_scale,
+                               interpret=(impl == "pallas_interpret"))
+    return _split_heads(kernel, (1, 2, 2, None, None), 1)(
+        q, k_pages, v_pages, block_tables, cache_len)
 
 
 def chunk_attention(q, k_cache, v_cache, start, chunk_len, *,
@@ -200,11 +236,13 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, start,
             return ref.chunk_attention_ref(q, k, v, start, chunk_len,
                                            prefix_len=prefix_len,
                                            softmax_scale=softmax_scale)
-        return paged_chunk_prefill_attention_quant_pallas(
-            q, k_pages.values, v_pages.values, k_pages.scales,
-            v_pages.scales, block_tables, start, chunk_len,
+        kernel = functools.partial(
+            paged_chunk_prefill_attention_quant_pallas,
             prefix_len=prefix_len, softmax_scale=softmax_scale,
             interpret=(impl == "pallas_interpret"))
+        return _split_heads(kernel, (2, 2, 2, 2, 2, None, None, None), 2)(
+            q, k_pages.values, v_pages.values, k_pages.scales,
+            v_pages.scales, block_tables, start, chunk_len)
     if impl == "ref":
         bs, trash = k_pages.shape[1], k_pages.shape[0] - 1
         end = jnp.asarray(start, jnp.int32) + jnp.asarray(chunk_len,
@@ -215,10 +253,12 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, start,
         return ref.chunk_attention_ref(q, k, v, start, chunk_len,
                                        prefix_len=prefix_len,
                                        softmax_scale=softmax_scale)
-    return paged_chunk_prefill_attention_pallas(
-        q, k_pages, v_pages, block_tables, start, chunk_len,
-        prefix_len=prefix_len, softmax_scale=softmax_scale,
-        interpret=(impl == "pallas_interpret"))
+    kernel = functools.partial(paged_chunk_prefill_attention_pallas,
+                               prefix_len=prefix_len,
+                               softmax_scale=softmax_scale,
+                               interpret=(impl == "pallas_interpret"))
+    return _split_heads(kernel, (2, 2, 2, None, None, None), 2)(
+        q, k_pages, v_pages, block_tables, start, chunk_len)
 
 
 def paged_verify_attention(q, k_pages, v_pages, block_tables, start,

@@ -12,7 +12,7 @@ Inputs are laid out per (b, h):
   dt : (BH, L, 1)      softplus-discretized step
   B  : (BH, L, N)      input projection (group-broadcast upstream)
   C  : (BH, L, N)      output projection
-  A  : (BH, 1)         per-head negative decay (SMEM)
+  A  : (BH,)          per-head negative decay (whole vector in SMEM)
   h0 : (BH, P, N)      initial state
 Outputs: y (BH, L, P) and final state (BH, P, N).
 """
@@ -25,9 +25,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
-
 DEFAULT_CHUNK = 128
+
+
+def _dot(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
@@ -38,7 +42,7 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
     def _init():
         state_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    A = a_ref[0, 0]
+    A = a_ref[pl.program_id(0)]
     x = x_ref[0].astype(jnp.float32)            # (Q, P)
     dt = dt_ref[0].astype(jnp.float32)          # (Q, 1)
     Bm = b_ref[0].astype(jnp.float32)           # (Q, N)
@@ -49,15 +53,23 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
     dt = jnp.where(tpos < seq_len, dt, 0.0)
 
     logdA = dt * A                               # (Q, 1), <= 0
-    cum = jnp.cumsum(logdA, axis=0)              # inclusive
-    # intra-chunk: M[t, s] = exp(cum_t - cum_s) * (C_t . B_s) * dt_s, s <= t
-    decay = jnp.exp(cum - cum.T)                 # (Q, Q) via broadcast
     q_iota = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_iota = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     tri = q_iota >= s_iota
+    # Mosaic has no cumsum or vector transpose, so the inclusive prefix
+    # sums come out of exact-f32 matmuls against triangular masks, once
+    # down the rows (cum_t) and once across the columns (cum_s, dt_s)
+    ones = jnp.ones((chunk, chunk), jnp.float32)
+    cum_t = _dot(tri.astype(jnp.float32),
+                 jnp.broadcast_to(logdA, (chunk, chunk)))       # [t, s] = cum_t
+    cum_s = _dot(ones, jnp.where(q_iota <= s_iota, logdA, 0.0))  # [t, s] = cum_s
+    dt_s = _dot(ones, jnp.where(q_iota == s_iota, dt, 0.0))      # [t, s] = dt_s
+    cum = cum_t[:, :1]                           # (Q, 1) inclusive
+    # intra-chunk: M[t, s] = exp(cum_t - cum_s) * (C_t . B_s) * dt_s, s <= t
+    decay = jnp.exp(cum_t - cum_s)               # (Q, Q)
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q, Q)
-    M = jnp.where(tri, decay * cb * dt.T, 0.0)
+    M = jnp.where(tri, decay * cb * dt_s, 0.0)
     y_intra = jax.lax.dot_general(M, x, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
@@ -70,7 +82,7 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: h' = exp(cum_last) * h + sum_s exp(cum_last - cum_s) dt_s x_s B_s^T
-    last = cum[chunk - 1, 0]
+    last = jnp.sum(logdA, axis=0, keepdims=True)  # (1, 1) = cum_{Q-1}
     w = jnp.exp(last - cum) * dt                 # (Q, 1)
     xw = x * w                                   # (Q, P)
     S = jax.lax.dot_general(xw, Bm, (((0,), (0,)), ((), ())),
@@ -104,7 +116,7 @@ def ssd_scan_pallas(x, dt, A, B, C, D=None, *, chunk=DEFAULT_CHUNK,
     Ch = jnp.repeat(padt(C), rep, axis=2).transpose(0, 2, 1, 3)
     Bh = Bh.reshape(Bb * H, Lp, N)
     Ch = Ch.reshape(Bb * H, Lp, N)
-    Ab = jnp.broadcast_to(A[None], (Bb, H)).reshape(Bb * H, 1)
+    Ab = jnp.broadcast_to(A[None], (Bb, H)).reshape(Bb * H)
     Ab = Ab.astype(jnp.float32)
     h0 = (jnp.zeros((Bb, H, P, N), jnp.float32) if initial_state is None
           else initial_state.astype(jnp.float32))
@@ -118,8 +130,9 @@ def ssd_scan_pallas(x, dt, A, B, C, D=None, *, chunk=DEFAULT_CHUNK,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, ci: (bh, 0),
-                         memory_space=pltpu.SMEM),
+            # the TPU refuses a (1, 1) SMEM block of an (BH, 1) array, so
+            # the whole decay vector sits in SMEM, indexed by program id
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, Q, P), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, Q, 1), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, Q, N), lambda bh, ci: (bh, ci, 0)),
@@ -135,7 +148,7 @@ def ssd_scan_pallas(x, dt, A, B, C, D=None, *, chunk=DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((Bb * H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(Ab, xt, dtt, Bh, Ch, h0)

@@ -27,8 +27,8 @@ import jax            # noqa: E402
 from repro.configs import ARCH_IDS, SHAPES_BY_NAME, config_for_shape  # noqa: E402
 from repro.launch import mesh as meshlib                   # noqa: E402
 from repro.launch.steps import build_step, lower_step      # noqa: E402
-from repro.roofline.analysis import (analyze_compiled,     # noqa: E402
-                                     model_flops_estimate)
+from repro.roofline.analysis import (DRYRUN_DEVICE_KIND,   # noqa: E402
+                                     analyze_compiled, model_flops_estimate)
 from repro.roofline.analytic import traffic                # noqa: E402
 
 
@@ -95,7 +95,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
                  optimizer=(bundle.optimizer if bundle.optimizer != "none"
                             else "adamw"),
                  fsdp=fsdp_params, serve_2d_tp=serve_2d_tp)
-    roof = analyze_compiled(name, compiled, chips,
+    roof = analyze_compiled(name, compiled, chips, DRYRUN_DEVICE_KIND,
                             model_flops=model_flops_estimate(cfg, shape),
                             hlo_text=compiled.as_text(),
                             analytic_traffic=tb)

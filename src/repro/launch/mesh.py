@@ -17,8 +17,8 @@ Sharding policy (baseline; hillclimbs recorded in EXPERIMENTS.md §Perf):
   caches     : batch on ``data`` when divisible, else sequence; kv-heads on
                ``model`` when divisible, else head_dim, else sequence.
 
-Every spec passes through ``_pick`` which only shards divisible dims —
-this jax version rejects uneven input shardings.
+Every spec passes through ``_pick``, which only shards divisible dims:
+jit rejects uneven input shardings.
 """
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig, ShapeSpec
 
@@ -38,11 +38,15 @@ MULTI_POD_SHAPE = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """A mesh whose axes the compiler partitions (``Auto``):
+    ``with_sharding_constraint`` and the rule-derived specs below assume
+    that, where ``jax.make_mesh``'s default Explicit axes reject them."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def axis_size(mesh: Mesh, name) -> int:
@@ -80,9 +84,9 @@ def _pick(mesh: Mesh, shape: Tuple[int, ...],
     spec = []
     for d in range(len(shape)):
         a = assignment.get(d)
-        if isinstance(a, (tuple, list)):  # unwrap singleton axis tuples so
-            a = a[0] if len(a) == 1 else tuple(a)  # specs compare equal on
-        spec.append(a)                    # JAX versions without normalization
+        if isinstance(a, (tuple, list)):  # unwrap singleton axis tuples
+            a = a[0] if len(a) == 1 else tuple(a)
+        spec.append(a)
     return P(*spec)
 
 
@@ -207,6 +211,29 @@ def cache_specs(mesh: Mesh, cache_shape, *,
         return _pick(mesh, shape, {} if replicate_batch else {baxes: [1]})
 
     return jax.tree_util.tree_map_with_path(spec_for, cache_shape)
+
+
+def arena_spec(mesh: Mesh, shape: Tuple[int, ...], *,
+               scales: bool = False) -> P:
+    """PartitionSpec for one serving-arena device buffer.  Page pools
+    ``(layers, pages, block_size, Hkv, D)`` and attention-shaped state
+    shard their head/head_dim axes over ``model`` — the same placement
+    ``cache_specs`` gives the dense cache — while the PAGE axis stays
+    replicated (the block-table page indirection must resolve locally;
+    model parallelism splits heads, not the pool).  An int8 pool's scale
+    sibling ``(layers, pages, block_size, Hkv)`` (``scales=True``) shards
+    only Hkv, the same heads as its values.  Smaller state leaves shard
+    their channel axis when divisible."""
+    nd = len(shape)
+    if scales:
+        prefs: Dict[Any, List[int]] = {"model": [nd - 1]}
+    elif nd >= 4:
+        prefs = {"model": [nd - 2, nd - 1]}
+    elif nd >= 3:
+        prefs = {"model": [nd - 1]}
+    else:
+        prefs = {}
+    return _pick(mesh, tuple(shape), prefs)
 
 
 def opt_state_specs(mesh: Mesh, opt_shape, params_spec) -> Any:

@@ -4,8 +4,10 @@ drive batched requests end-to-end (the paper-kind driver).
 Each "edge server" is a ServiceRuntime deployment; the EPARA allocator
 picks (MP, BS, MT, MF, DP) per service, the SSSP placement assigns services
 to servers, and the distributed handler routes every request (local first,
-then idle-goodput-weighted offload).  On CPU the models are reduced
-variants; on TPU the same engine takes pjit'd step functions.
+then idle-goodput-weighted offload).  ``--size reduced`` (the default)
+serves 2-layer, d_model-128 variants sized for CPU tests; ``--size full``
+serves each config at its published widths.  Weights are random, drawn
+from ``--seed`` and the arch id, so every process builds the same model.
 
   PYTHONPATH=src python -m repro.launch.serve --archs minicpm-2b,mamba2-2.7b \
       --servers 3 --requests 24
@@ -13,12 +15,18 @@ variants; on TPU the same engine takes pjit'd step functions.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import pathlib
 import time
+import zlib
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, reduced
+from repro.kernels import ops
 from repro.core import (EdgeCloudControlPlane, GPUSpec, Outcome, Request,
                         ServerSpec, ServiceSpec, Sensitivity, allocate)
 from repro.core.faults import FaultInjector, FaultSpec, random_fault_spec
@@ -42,12 +50,71 @@ def service_spec_for(cfg) -> ServiceSpec:
         prefix_cacheable=cfg.family in PREFIX_CACHEABLE_FAMILIES)
 
 
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def setup_compile_cache() -> Optional[str]:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads it itself), else in a
+    fixed ``.jax_cache/`` at the checkout root — a fixed path, because the
+    path is part of what a later process must match to hit.  The CPU
+    backend gets no default cache: its compiles are quick, and XLA:CPU's
+    cached code is tied to the CPU features of the host that built it."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path is None and jax.default_backend() != "cpu":
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_params(cfg, seed: int, mesh=None):
+    """Random weights from ``seed`` and the arch id (``crc32``, never the
+    per-process salted ``hash``), initialised by one jitted program so the
+    peak is the parameters themselves; under a mesh they are born with
+    the serving shardings (``meshlib.param_specs``, pure tensor
+    parallelism), so no device ever holds the whole model."""
+    init = model_api(cfg).init
+    key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                             zlib.crc32(cfg.name.encode()))
+    out_shardings = None
+    if mesh is not None:
+        from repro.launch import mesh as meshlib
+        shapes = jax.eval_shape(lambda k: init(k, cfg), key)
+        out_shardings = meshlib.named(
+            mesh, meshlib.param_specs(mesh, shapes, fsdp=False))
+    return jax.jit(lambda k: init(k, cfg), out_shardings=out_shardings)(key)
+
+
+@dataclasses.dataclass
+class ServeRun:
+    """What one launcher run served, for callers that check it."""
+    exit_code: int
+    prompts: Dict[int, np.ndarray]        # rid -> prompt tokens
+    results: List[Any]                    # GenerationResult per served rid
+    cfgs: Dict[str, Any]                  # service -> served config
+    params: Dict[str, Any]                # service -> weights
+    slots: Dict[str, int]                 # service -> arena slots per group
+    decode_traces: int
+    serve_s: float                        # wall clock of the serving loop
+
+
 def main(argv=None) -> int:
+    return serve(argv).exit_code
+
+
+def serve(argv=None) -> ServeRun:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--archs", default="minicpm-2b,mamba2-2.7b")
     ap.add_argument("--servers", type=int, default=3)
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--max-new-tokens", type=int, default=8)
+    ap.add_argument("--prompt-len", default="6",
+                    help="prompt tokens per request: N, or LO,HI for "
+                         "lengths drawn uniformly from [LO, HI]")
+    ap.add_argument("--size", choices=("reduced", "full"), default="reduced",
+                    help="reduced = 2-layer d_model-128 variants (CPU "
+                         "tests); full = the published config")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--mode", choices=("continuous", "sync"),
                     default="continuous",
@@ -210,7 +277,19 @@ def main(argv=None) -> int:
     if args.retry_max_attempts < 1:
         ap.error(f"--retry-max-attempts must be >= 1, got "
                  f"{args.retry_max_attempts}")
+    try:
+        bounds = [int(x) for x in args.prompt_len.split(",")]
+    except ValueError:
+        bounds = []
+    plo, phi = (bounds * 2)[:2] if len(bounds) in (1, 2) else (0, 0)
+    if not 1 <= plo <= phi:
+        ap.error(f"--prompt-len must be N or LO,HI with 1 <= LO <= HI, got "
+                 f"{args.prompt_len!r}")
     kv_dtype = -1 if args.kv_dtype == "auto" else args.kv_dtype
+    dev = jax.devices()[0]
+    print(f"backend={jax.default_backend()} device_kind={dev.device_kind} "
+          f"devices={jax.device_count()} impl={ops.default_impl()} "
+          f"size={args.size}")
 
     arch_ids = [a.strip() for a in args.archs.split(",")]
     for a in arch_ids:
@@ -223,7 +302,7 @@ def main(argv=None) -> int:
     for a in arch_ids:
         full = get_config(a)
         specs[a] = service_spec_for(full)
-        cfgs[a] = reduced(full)          # CPU-sized data plane
+        cfgs[a] = full if args.size == "full" else reduced(full)
     cp = EdgeCloudControlPlane(servers, specs)
     demand = {(a, s.sid): 4.0 for a in arch_ids for s in servers}
     placements = cp.run_placement(demand)
@@ -247,27 +326,26 @@ def main(argv=None) -> int:
         from repro.obs import MetricsRegistry
         metrics = MetricsRegistry()
     rng = np.random.default_rng(args.seed)
-    import dataclasses as _dc
-    step_builder = None
+    service_mesh = None
     if args.pjit_decode:
-        # MP-sharded paged decode: the same pure fused step, jitted with
-        # the service mesh's shardings (launch/steps.paged_decode_builder)
+        # MP-sharded serving: weights, arena and every arena step live on
+        # the (1, device_count) service mesh (heads over ``model``)
         from repro.launch import mesh as meshlib
-        from repro.launch.steps import paged_decode_builder
         service_mesh = meshlib.make_mesh((1, jax.device_count()),
                                          ("data", "model"))
-        step_builder = paged_decode_builder(service_mesh)
     draft_cfg = draft_params = None
     if args.draft_arch:
-        draft_cfg = reduced(get_config(args.draft_arch))
-        draft_params = model_api(draft_cfg).init(
-            jax.random.PRNGKey(hash(args.draft_arch) % 2**31), draft_cfg)
+        draft_cfg = get_config(args.draft_arch)
+        if args.size == "reduced":
+            draft_cfg = reduced(draft_cfg)
+        draft_params = init_params(draft_cfg, args.seed, service_mesh)
+    weights = {a: init_params(cfgs[a], args.seed, service_mesh)
+               for a in {svc for svc, sid in placements if sid >= 0}}
     for svc, sid in placements:
         if sid < 0:
             continue
         cfg = cfgs[svc]
-        params = model_api(cfg).init(jax.random.PRNGKey(hash(svc) % 2**31),
-                                     cfg)
+        params = weights[svc]
         chunked = (None if not args.no_chunked_prefill else False)
         # the draft only pairs with same-family same-vocab attention
         # services; the rest deploy non-speculative (an explicit
@@ -279,17 +357,18 @@ def main(argv=None) -> int:
         if draft_cfg is not None and not compat and args.speculate <= 0:
             print(f"  note: {svc} incompatible with draft "
                   f"{args.draft_arch} (family/vocab) — non-speculative")
-        plan = _dc.replace(cp.plans[svc], prefix_cache=args.prefix_cache,
-                           kv_dtype=kv_dtype,
-                           admission=args.admission_policy,
-                           speculate=args.speculate)
+        plan = dataclasses.replace(cp.plans[svc],
+                                   prefix_cache=args.prefix_cache,
+                                   kv_dtype=kv_dtype,
+                                   admission=args.admission_policy,
+                                   speculate=args.speculate)
         rt = ServiceRuntime(cfg, params, plan, mode=args.mode,
                             kvcache_impl=args.kvcache_impl,
                             max_seq_len=args.max_seq_len,
                             block_size=args.block_size,
                             chunked_prefill=chunked,
                             prefill_chunk=(args.prefill_chunk or None),
-                            paged_step_builder=step_builder,
+                            mesh=service_mesh,
                             preempt=not args.no_preempt,
                             draft_params=draft_params if compat else None,
                             draft_cfg=draft_cfg if compat else None,
@@ -327,11 +406,14 @@ def main(argv=None) -> int:
                           max_attempts=args.retry_max_attempts),
         injector=FaultInjector(fault_spec) if fault_spec else None,
         metrics=metrics, tracer=tracer)
+    prompts = {}
     for i in range(args.requests):
         svc = arch_ids[i % len(arch_ids)]
         at = int(rng.integers(0, len(servers)))
         cfg = cfgs[svc]
-        prompt = rng.integers(0, cfg.vocab_size, size=6).astype(np.int32)
+        n = plo if plo == phi else int(rng.integers(plo, phi + 1))
+        prompt = rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+        prompts[i] = prompt
         extras = None
         if cfg.family in ("audio", "vlm"):
             dim = cfg.encoder_len if cfg.family == "audio" else cfg.prefix_len
@@ -346,6 +428,12 @@ def main(argv=None) -> int:
     report = supervisor.run_until_idle(clock=clock)
     results = report.results
     outcomes = report.outcomes
+    rts = [rt for eng in engines.values() for rt in eng.runtimes.values()]
+    # the served tokens are on the host; also wait out the arenas' last
+    # in-place updates so the clock covers all device work
+    jax.block_until_ready([(g.arena.pages, g.arena.state, g.arena.lens)
+                           for rt in rts for g in rt.groups.values()
+                           if g.arena is not None])
     dt = time.monotonic() - t0
     toks = sum(len(r.tokens) for r in results)
     steps = sum(rt.decode_steps for eng in engines.values()
@@ -360,7 +448,16 @@ def main(argv=None) -> int:
           f"in {dt:.2f}s ({toks/dt:.1f} tok/s, {steps} fused decode steps, "
           f"mode={args.mode}, kvcache={args.kvcache_impl})  "
           f"outcomes={outcomes}")
-    rts = [rt for eng in engines.values() for rt in eng.runtimes.values()]
+    slots = {}
+    for eng in engines.values():
+        for name, rt in eng.runtimes.items():
+            caps = [g.arena.capacity for g in rt.groups.values()
+                    if g.arena is not None]
+            if caps:
+                slots[name] = min(caps)
+                print(f"arena: {name} {min(caps)} slots per group of plan "
+                      f"bs={rt.plan.bs}, {rt.max_seq_len} tokens each, "
+                      f"kv={rt.kv_dtype}")
     chunk_calls = sum(rt.prefill_chunk_calls for rt in rts)
     pf_traces = sum(rt.prefill_traces for rt in rts)
     chunk_mb = sum(rt.chunk_write_bytes for rt in rts) / 1e6
@@ -431,7 +528,10 @@ def main(argv=None) -> int:
               f"prefill_token_s={cal.prefill_token_s:.2e} -> "
               f"{args.calibrate_out}")
     # every request is accounted for: served, or rejected with a verdict
-    return 0 if report.accounted == args.requests else 1
+    return ServeRun(
+        exit_code=0 if report.accounted == args.requests else 1,
+        prompts=prompts, results=results, cfgs=cfgs,
+        params=weights, slots=slots, decode_traces=traces, serve_s=dt)
 
 
 if __name__ == "__main__":

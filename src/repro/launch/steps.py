@@ -18,10 +18,9 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding
 
 from repro.configs import config_for_shape
-from repro.kernels.quant import QuantPages
 from repro.models.config import ModelConfig, SHAPES_BY_NAME, ShapeSpec
 from repro.models.registry import input_specs, model_api
 from repro.training.optimizer import get_optimizer
@@ -191,69 +190,3 @@ def build_step(arch: str, shape_name: str, mesh: Mesh, *,
 def lower_step(bundle: StepBundle):
     with bundle.mesh:
         return bundle.fn.lower(*bundle.args)
-
-
-# ---------------------------------------------------------------------------
-# serving: pjit'd paged decode (MP-sharded zero-gather hot loop)
-# ---------------------------------------------------------------------------
-
-def _paged_leaf_spec(mesh: Mesh, leaf):
-    """PartitionSpec for one arena device buffer.  Page pools
-    ``(layers, pages, block_size, Hkv, D)`` and attention-shaped state
-    shard their head/head_dim axes over the model axis — the same
-    placement ``meshlib.cache_specs`` gives the dense cache — while the
-    PAGE axis stays replicated (the block-table page indirection must
-    resolve locally; model parallelism splits heads, not the pool).
-    Smaller state leaves shard their channel axis when divisible."""
-    nd = leaf.ndim
-    if nd >= 4:
-        prefs: Dict[Any, list] = {"model": [nd - 2, nd - 1]}
-    elif nd >= 3:
-        prefs = {"model": [nd - 1]}
-    else:
-        prefs = {}
-    return meshlib._pick(mesh, tuple(leaf.shape), prefs)
-
-
-def _pool_sharding(mesh: Mesh, pool):
-    """Sharding(s) for one page pool.  Quantized pools are a two-leaf
-    pytree: the int8 values shard like a dense pool (heads/head_dim over
-    ``model``), the per-row scale pool ``(layers, pages, block_size, Hkv)``
-    shards only its trailing Hkv axis — the same head placement as the
-    values, never the token axis."""
-    if isinstance(pool, QuantPages):
-        vspec = _paged_leaf_spec(mesh, pool.values)
-        sspec = meshlib._pick(mesh, tuple(pool.scales.shape),
-                              {"model": [pool.scales.ndim - 1]})
-        return QuantPages(NamedSharding(mesh, vspec),
-                          NamedSharding(mesh, sspec))
-    return NamedSharding(mesh, _paged_leaf_spec(mesh, pool))
-
-
-def paged_decode_builder(mesh: Mesh, *, fsdp_params: bool = False):
-    """Builder for ``ServiceRuntime(paged_step_builder=...)``: jits the
-    engine's pure fused paged decode step under the service mesh so
-    MP-sharded paged decode works — params shard by the standard rules,
-    page pools / per-slot state shard their head axes over ``model``,
-    and the host-fed control operands (tokens, lens, live, block tables)
-    replicate.  The paged-native zero-gather step and the dense-view
-    fallback both build this way; the arena's donated buffers still
-    update in place under pjit."""
-
-    def builder(runtime, arena):
-        params_shape = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            runtime.params)
-        psharding = meshlib.named(mesh, meshlib.param_specs(
-            mesh, params_shape, fsdp=fsdp_params))
-        pages_sh = [_pool_sharding(mesh, p) for p in arena.pages]
-        state_sh = [NamedSharding(mesh, _paged_leaf_spec(mesh, s))
-                    for s in arena.state]
-        rep = NamedSharding(mesh, P())
-        return jax.jit(
-            runtime._paged_decode_pure(arena),
-            in_shardings=(psharding, rep, pages_sh, state_sh, rep, rep,
-                          rep),
-            donate_argnums=arena._donate_argnums((2, 3, 4)))
-
-    return builder

@@ -21,9 +21,11 @@ from .sharding import constrain_activation
 
 
 def stack_layer_params(key, n: int, init_fn):
-    keys = jax.random.split(key, n)
-    ps = [init_fn(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *ps)
+    """``n`` layers' params stacked on a leading axis.  ``vmap`` over the
+    per-layer keys draws the same values as initialising layer by layer,
+    but writes the stack directly — no per-layer copies to concatenate,
+    so a jitted init peaks at the parameters' own size."""
+    return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
 def init_block(key, cfg: ModelConfig):

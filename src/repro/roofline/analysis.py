@@ -13,8 +13,8 @@ in cost_analysis: we parse the optimized HLO, take each collective op's
 per-device result-shard bytes, and convert to wire bytes with the standard
 ring-algorithm factors.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware peaks come from ``PEAKS``, keyed by the chip's ``device_kind``;
+a device that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -23,9 +23,22 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes / s
-ICI_BW = 50e9                # bytes / s / link
+# Published per-chip peaks by ``jax.devices()[0].device_kind``.  TPU v5e
+# ("TPU v5 lite"), Google Cloud documentation "TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip interconnect over its 4 ICI
+# links (50 GB/s a link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"   # the chip the dry-run meshes model
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -115,6 +128,7 @@ def parse_collectives(hlo_text: str) -> Dict[str, CollectiveStats]:
 @dataclasses.dataclass
 class Roofline:
     name: str
+    device_kind: str                 # selects the PEAKS row
     chips: int
     flops_per_device: float
     bytes_per_device: float          # analytic TPU-fusion HBM traffic
@@ -127,17 +141,21 @@ class Roofline:
     traffic_breakdown: Dict[str, float] = dataclasses.field(
         default_factory=dict)
 
+    def __post_init__(self):
+        peaks(self.device_kind)
+
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / peaks(self.device_kind)["flops"]
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / peaks(self.device_kind)["hbm_bw"]
 
     @property
     def collective_s(self) -> float:
-        return self.collective_wire_bytes / ICI_BW
+        return (self.collective_wire_bytes
+                / peaks(self.device_kind)["ici_bw"])
 
     @property
     def dominant(self) -> str:
@@ -160,7 +178,8 @@ class Roofline:
 
     def to_dict(self) -> Dict:
         return {
-            "name": self.name, "chips": self.chips,
+            "name": self.name, "device_kind": self.device_kind,
+            "chips": self.chips,
             "flops_per_device": self.flops_per_device,
             "bytes_per_device": self.bytes_per_device,
             "hlo_bytes_per_device": self.hlo_bytes_per_device,
@@ -175,7 +194,7 @@ class Roofline:
         }
 
 
-def analyze_compiled(name: str, compiled, chips: int, *,
+def analyze_compiled(name: str, compiled, chips: int, device_kind: str, *,
                      model_flops: float = 0.0,
                      hlo_text: Optional[str] = None,
                      analytic_traffic=None) -> Roofline:
@@ -203,7 +222,8 @@ def analyze_compiled(name: str, compiled, chips: int, *,
                               - mem["alias_size_in_bytes"])
     mem["xla_flops_once"] = float(ca.get("flops", 0.0))
     mem["xla_bytes_once"] = float(ca.get("bytes accessed", 0.0))
-    return Roofline(name=name, chips=chips, flops_per_device=flops,
+    return Roofline(name=name, device_kind=device_kind, chips=chips,
+                    flops_per_device=flops,
                     bytes_per_device=bytes_, collective_wire_bytes=wire,
                     collective_counts=counts, memory_stats=mem,
                     model_flops=model_flops,

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.quant import QuantPages, dequantize, quantize
+from repro.launch import mesh as meshlib
 
 Cache = Any  # pytree of arrays
 
@@ -79,7 +80,7 @@ class KVArena:
     def __init__(self, cfg, init_cache: Callable, *, capacity: int,
                  max_seq_len: int, block_size: int = 32,
                  pool_blocks: Optional[int] = None, dtype=None,
-                 kv_dtype: str = "bf16"):
+                 kv_dtype: str = "bf16", mesh=None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         if kv_dtype not in VALID_KV_DTYPES:
@@ -93,6 +94,10 @@ class KVArena:
         # encoder cross-KV, saturated ring windows) are never quantized.
         self.kv_dtype = kv_dtype
         self.cfg = cfg
+        # under a model-parallel service mesh every buffer is born sharded
+        # (``meshlib.arena_spec``): heads split over ``model``, pages and
+        # slots replicated, so no device ever holds the whole pool
+        self.mesh = mesh
         self.capacity = int(capacity)
         self.block_size = int(block_size)
         self.blocks_per_slot = max(1, math.ceil(max_seq_len / block_size))
@@ -153,18 +158,18 @@ class KVArena:
                 self._quantized.append(quant)
                 if quant:
                     self.pages.append(QuantPages(
-                        jnp.zeros((A0, P1, self.block_size, *rest),
-                                  jnp.int8),
-                        jnp.zeros((A0, P1, self.block_size, *rest[:-1]),
-                                  jnp.float32)))
+                        self._zeros((A0, P1, self.block_size, *rest),
+                                    jnp.int8),
+                        self._zeros((A0, P1, self.block_size, *rest[:-1]),
+                                    jnp.float32, scales=True)))
                 else:
-                    self.pages.append(jnp.zeros(
+                    self.pages.append(self._zeros(
                         (A0, P1, self.block_size, *rest), self._dtypes[i]))
             elif tag == _STATE:
                 A0, _, *rest = lo_leaves[i].shape
-                self.state.append(jnp.zeros((A0, self.capacity, *rest),
-                                            self._dtypes[i]))
-        self.lens = jnp.zeros((self.capacity,), jnp.int32)
+                self.state.append(self._zeros((A0, self.capacity, *rest),
+                                              self._dtypes[i]))
+        self.lens = self._zeros((self.capacity,), jnp.int32)
 
         # -- host bookkeeping ----------------------------------------------
         self._block_tables = np.full(
@@ -222,6 +227,35 @@ class KVArena:
             for s, d in zip(self._state_shapes,
                             (self._dtypes[i] for i, t in
                              enumerate(self._tags) if t == _STATE)))
+
+    def _zeros(self, shape, dtype, *, scales: bool = False):
+        if self.mesh is None:
+            return jnp.zeros(shape, dtype)
+        spec = meshlib.arena_spec(self.mesh, tuple(shape), scales=scales)
+        return jnp.zeros(shape, dtype,
+                         device=jax.sharding.NamedSharding(self.mesh, spec))
+
+    def shardings(self) -> Optional[Tuple[Any, Any, Any]]:
+        """``(pages, state, lens)`` placements under a mesh (``None``
+        without one): a step that returns the arena's buffers pins them
+        here, so they never reshard and the next call hits the same
+        compile."""
+        if self.mesh is None:
+            return None
+        return jax.tree.map(lambda a: a.sharding,
+                            (self.pages, self.state, self.lens))
+
+    def device_slot_bytes(self) -> int:
+        """Bytes one more slot costs on each device holding the arena:
+        its blocks' share of every page pool plus its state row."""
+        def shard_bytes(a):
+            return (math.prod(a.sharding.shard_shape(a.shape))
+                    * a.dtype.itemsize)
+        per_block = (sum(shard_bytes(p) for p in jax.tree.leaves(self.pages))
+                     / (self.pool_blocks + 1))
+        per_state = (sum(shard_bytes(s) for s in self.state) + shard_bytes(
+            self.lens)) / self.capacity
+        return math.ceil(per_block * self.blocks_per_slot + per_state)
 
     # ------------------------------------------------------------------
     # allocator surface
@@ -499,7 +533,9 @@ class KVArena:
                 # pools' leading (layers, blocks) layout)
                 return jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]),
                                     pages)
-            fn = jax.jit(_copy, donate_argnums=self._donate_argnums((0,)))
+            fn = jax.jit(_copy, donate_argnums=(0,),
+                         out_shardings=(None if self.mesh is None
+                                        else self.shardings()[0]))
             self._cow_many_fns[n] = fn
         self.pages = fn(self.pages, jnp.asarray(src), jnp.asarray(dst))
         self._tables_dev = None
@@ -566,13 +602,6 @@ class KVArena:
     # ------------------------------------------------------------------
     # admission write path
     # ------------------------------------------------------------------
-    @staticmethod
-    def _donate_argnums(nums: Tuple[int, ...]) -> Tuple[int, ...]:
-        """Donate the arena's device buffers so XLA updates pages/state in
-        place instead of re-materializing the pool every call (CPU has no
-        donation support, so skip it there to avoid per-compile warnings)."""
-        return nums if jax.default_backend() != "cpu" else ()
-
     def write_prefill(self, slot: int, cache: Cache,
                       prompt_len: int) -> int:
         """Scatter one freshly prefilled single-request cache (batch 1,
@@ -587,7 +616,8 @@ class KVArena:
         if fn is None:
             fn = jax.jit(functools.partial(self._write_prefill_impl,
                                            n_blocks=n_blocks),
-                         donate_argnums=self._donate_argnums((0, 1, 2)))
+                         donate_argnums=(0, 1, 2),
+                         out_shardings=self.shardings())
             self._write_fns[n_blocks] = fn
         self.pages, self.state, self.lens = fn(
             self.pages, self.state, self.lens, cache,
@@ -748,7 +778,7 @@ class KVArena:
         must not absorb the masked step's garbage)."""
         out = []
         for old, new in zip(state, state_new):
-            mask = live.reshape(1, self.capacity,
+            mask = live.reshape(1, live.shape[0],
                                 *([1] * (old.ndim - 2)))
             out.append(jnp.where(mask, new.astype(old.dtype), old))
         return out

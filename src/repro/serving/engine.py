@@ -29,7 +29,8 @@ counts land in ``StepStats``,
 Two cache data planes back the slot loop (``kvcache_impl``):
 
 * ``"paged"`` (default) — a fixed-capacity ``KVArena`` per group, sized
-  from the plan (``plan.max_in_flight`` slots x paged token blocks).
+  from the plan (``plan.max_in_flight`` slots x paged token blocks,
+  clipped to what the device's memory holds).
   Admission scatters only the new request's pages into the arena
   (``arena.alloc`` + ``arena.write_prefill``), eviction is a free-list
   operation, and decode always runs at the full static ``(capacity, ...)``
@@ -86,7 +87,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -313,6 +314,21 @@ class _GroupState:
         return len(self.slots)
 
 
+class _MeshStep:
+    """A jitted step that traces, compiles and runs under its mesh."""
+
+    def __init__(self, jitted, mesh):
+        self.jitted, self.mesh = jitted, mesh
+
+    def __call__(self, *args):
+        with jax.set_mesh(self.mesh):
+            return self.jitted(*args)
+
+    def lower(self, *args):
+        with jax.set_mesh(self.mesh):
+            return self.jitted.lower(*args)
+
+
 class ServiceRuntime:
     """One deployed service: params + plan + DP groups of decode slots."""
 
@@ -329,7 +345,7 @@ class ServiceRuntime:
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: Optional[Any] = None,
                  paged_native: Optional[bool] = None,
-                 paged_step_builder: Optional[Callable] = None,
+                 mesh=None,
                  on_evict: Optional[Callable] = None,
                  admission_policy: Optional[str] = None,
                  preempt: bool = True,
@@ -445,7 +461,9 @@ class ServiceRuntime:
                 f"{ring}) — pure-SSM families and ring (sliding-window) "
                 "layouts keep the state/dense-view path")
         self.paged_native = bool(paged_native)
-        self.paged_step_builder = paged_step_builder
+        # model-parallel service mesh (``None`` = one device): arenas are
+        # born sharded over it and every arena step is jitted under it
+        self.mesh = mesh
         if chunked_prefill is None:
             chunked_prefill = (mode == "continuous"
                                and kvcache_impl == "paged" and not ring)
@@ -666,7 +684,7 @@ class ServiceRuntime:
         return sum(g.live for g in self.groups.values())
 
     def total_slots(self) -> int:
-        return self.plan.max_in_flight * len(self.groups)
+        return sum(self._group_slots(g) for g in self.groups.values())
 
     # -- shared helpers ---------------------------------------------------
     def _pad_prompts(self, reqs: Sequence[GenerationRequest]):
@@ -826,7 +844,7 @@ class ServiceRuntime:
     # continuous mode: slot admit / fused decode / evict
     # ------------------------------------------------------------------
     def _free_slots(self) -> int:
-        return sum(max(0, self.plan.bs - g.live)
+        return sum(max(0, self._group_slots(g) - g.live)
                    for g in self.groups.values())
 
     def _evict(self, group: int, state: _GroupState,
@@ -886,13 +904,110 @@ class ServiceRuntime:
                 self.admission_copy_bytes += kvcache.cache_bytes(state.cache)
         return results
 
+    def _group_slots(self, state: _GroupState) -> int:
+        """Decode slots of one DP group: its arena's capacity once built
+        (the plan's ``bs`` clipped to device memory), the plan's before."""
+        return (state.arena.capacity if state.arena is not None
+                else self.plan.max_in_flight)
+
+    def _new_arena(self, capacity: int) -> KVArena:
+        return KVArena(self.cfg, self.api.init_cache, capacity=capacity,
+                       max_seq_len=self.max_seq_len,
+                       block_size=self.block_size,
+                       pool_blocks=self.pool_blocks, kv_dtype=self.kv_dtype,
+                       mesh=self.mesh)
+
+    def _fit_capacity(self) -> int:
+        """Slots for a new group arena: the plan's ``max_in_flight``
+        clipped to what the device's memory holds after everything
+        already on it (weights, other arenas) and the fused steps' own
+        temporaries — ``plan.bs`` itself stays what EPARA chose.  Backends
+        that report no memory limit (CPU) and explicit ``pool_blocks``
+        keep the plan's count.  Raises when not even one slot of
+        ``max_seq_len`` fits: shrinking the per-slot budget would change
+        which requests the service accepts."""
+        want = self.plan.max_in_flight
+        free = self._free_device_bytes()
+        if free is None or self.pool_blocks is not None:
+            return want
+        # DP groups still without an arena share what is left evenly
+        free //= sum(1 for g in self.groups.values() if g.arena is None)
+        probe = self._new_arena(1)
+        per_slot = probe.device_slot_bytes()
+        # the temporaries at ``cap`` slots bound those of any smaller
+        # count, so ``min(cap, fit)`` fits; when they leave no slot, or
+        # the compiler itself finds the program too big for the device,
+        # step down and compile again
+        cap = min(want, free // per_slot)
+        while cap >= 1:
+            try:
+                scratch = self._step_scratch_bytes(probe, cap)
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                cap = cap * 7 // 8
+                continue
+            fit = (free - scratch) // per_slot
+            if fit >= 1:
+                cap = min(cap, fit)
+                break
+            cap = cap * 7 // 8
+        if cap < 1:
+            raise MemoryError(
+                f"{self.cfg.name}: one arena slot of max_seq_len="
+                f"{self.max_seq_len} needs {per_slot} device bytes, but "
+                f"only {free} are free after the weights (less the fused "
+                f"steps' temporaries)")
+        return int(cap)
+
+    def _free_device_bytes(self) -> Optional[int]:
+        """Least free memory over the devices this runtime's arena lives
+        on, or ``None`` where the backend reports no limit (CPU)."""
+        devices = (list(self.mesh.devices.flat) if self.mesh is not None
+                   else [jax.devices()[0]])
+        stats = [d.memory_stats() or {} for d in devices]
+        if any("bytes_limit" not in st for st in stats):
+            return None
+        return min(st["bytes_limit"] - st["bytes_in_use"] for st in stats)
+
+    def _step_scratch_bytes(self, probe: KVArena, capacity: int) -> int:
+        """Device bytes a fused decode or chunk call allocates beyond its
+        donated arguments at ``capacity`` slots (temporaries plus
+        un-aliased outputs, from ``memory_analysis`` of the compiled
+        steps), compiled against ``probe``'s layout without allocating
+        the full arena."""
+        bps = probe.blocks_per_slot
+
+        def grown(a, n):
+            return jax.ShapeDtypeStruct((a.shape[0], n, *a.shape[2:]),
+                                        a.dtype, sharding=a.sharding)
+
+        pages = jax.tree.map(lambda p: grown(p, capacity * bps + 1),
+                             probe.pages)
+        state = [grown(st, capacity) for st in probe.state]
+        lens = jax.ShapeDtypeStruct((capacity,), jnp.int32,
+                                    sharding=probe.lens.sharding)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        T = self.chunk_buckets[-1]
+        counters = (self.decode_traces, self.prefill_traces)
+        try:
+            steps = [self._build_paged_decode_fn(probe).lower(
+                self.params, i32(capacity), pages, state, lens,
+                jax.ShapeDtypeStruct((capacity,), bool),
+                i32(capacity, bps))]
+            if self.chunked_prefill:
+                steps.append(self._build_chunk_fn(probe, T, False).lower(
+                    self.params, i32(1, T), None, pages, state, lens,
+                    i32(), i32(bps), i32()))
+            mems = [s.compile().memory_analysis() for s in steps]
+        finally:
+            self.decode_traces, self.prefill_traces = counters
+        return max(m.temp_size_in_bytes + m.output_size_in_bytes
+                   - m.alias_size_in_bytes for m in mems)
+
     def _ensure_arena(self, state: _GroupState) -> KVArena:
         if state.arena is None:
-            state.arena = KVArena(
-                self.cfg, self.api.init_cache,
-                capacity=self.plan.max_in_flight,
-                max_seq_len=self.max_seq_len, block_size=self.block_size,
-                pool_blocks=self.pool_blocks, kv_dtype=self.kv_dtype)
+            state.arena = self._new_arena(self._fit_capacity())
             if self.prefix_cache_enabled:
                 state.prefix = RadixPrefixCache(
                     state.arena,
@@ -1245,14 +1360,15 @@ class ServiceRuntime:
         still: its frozen blocks are physical ids in ONE group's arena."""
         pg = self.admission.parked_group(item.rid)
         if pg is not None:
-            return pg if self.groups[pg].live < self.plan.bs else None
+            pgs = self.groups[pg]
+            return pg if pgs.live < self._group_slots(pgs) else None
         g = self.router.route(session=item.stream)
-        if self.groups[g].live < self.plan.bs:
+        if self.groups[g].live < self._group_slots(self.groups[g]):
             return g
         if self.plan.sticky and item.stream:
             return None          # session pinned to a full group: requeue
         for alt, state in self.groups.items():
-            if state.live < self.plan.bs:
+            if state.live < self._group_slots(state):
                 return alt
         return None
 
@@ -1363,8 +1479,7 @@ class ServiceRuntime:
                      for s, ns in zip(state, new_state)]
             return logits, new_pages, state, lens.at[slot].set(new_len)
 
-        return jax.jit(_chunk, donate_argnums=arena._donate_argnums((3, 4,
-                                                                     5)))
+        return self._arena_jit(_chunk, arena, (3, 4, 5))
 
     def _run_chunk(self, arena: KVArena, s: _Slot, T: int) -> Any:
         """Advance one slot's prefill by one ``T``-bucket chunk; returns
@@ -1480,8 +1595,9 @@ class ServiceRuntime:
         if state.draft is None:
             state.draft = KVArena(
                 self.draft_cfg, self.draft_api.init_cache,
-                capacity=self.plan.max_in_flight,
+                capacity=state.arena.capacity,   # slot ids pair up
                 max_seq_len=self.max_seq_len, block_size=self.block_size,
+                mesh=self.mesh,
                 kv_dtype="bf16")   # draft KV stays native precision: its
             #                        proposals are re-scored by the target
             #                        anyway, but int8 would change WHICH
@@ -1587,8 +1703,7 @@ class ServiceRuntime:
             new_lens = jnp.where(spec, lens + n_emit, lens)
             return out, n_emit, new_pages, state2, new_lens
 
-        return jax.jit(_verify,
-                       donate_argnums=arena._donate_argnums((4, 5, 6)))
+        return self._arena_jit(_verify, arena, (4, 5, 6), n_lead=2)
 
     def _spec_round(self, state: _GroupState,
                     spec_slots: List[_Slot]) -> None:
@@ -1617,11 +1732,11 @@ class ServiceRuntime:
             offs[sid] = len(s.emitted)
         live_dev = jnp.asarray(live)
         if self._draft_decode_fn is None:
-            self._draft_decode_fn = jax.jit(
+            self._draft_decode_fn = self._arena_jit(
                 self._paged_decode_pure(draft, api=self.draft_api,
                                         cfg=self.draft_cfg, native=True,
                                         counter="draft_decode_traces"),
-                donate_argnums=draft._donate_argnums((2, 3, 4)))
+                draft, (2, 3, 4))
         drafts_host: List[np.ndarray] = []
         dlogit_steps: List[Any] = []
         for j in range(k + 1):
@@ -1730,7 +1845,7 @@ class ServiceRuntime:
             [seed] * want, list(range(1, want + 1)), [0] * want))
         spawned = 0
         for i in range(want):
-            if (state.live >= self.plan.bs
+            if (state.live >= self._group_slots(state)
                     or not arena.can_alloc(total, shared=shared)):
                 break
             sid = arena.alloc(total, shared=shared)
@@ -1763,8 +1878,8 @@ class ServiceRuntime:
         """The fused decode step as a PURE function of
         ``(params, tokens, pages, state, lens, live, block_tables)`` ->
         ``(logits, pages, state, lens)`` — what ``_build_paged_decode_fn``
-        jits locally and what a launcher's ``paged_step_builder`` wraps in
-        ``pjit`` with mesh shardings for MP-sharded paged decode.
+        jits, on one device or under the service mesh (MP-sharded paged
+        decode).
 
         ``api``/``cfg``/``native``/``counter`` default to the TARGET
         model; the speculative path passes the DRAFT model's to build the
@@ -1804,12 +1919,24 @@ class ServiceRuntime:
         return _step
 
     def _build_paged_decode_fn(self, arena: KVArena):
-        if self.paged_step_builder is not None:
-            return self.paged_step_builder(self, arena)
         # donate the arena buffers (args 2..4) so XLA appends in place
         # instead of re-materializing the page pool every decode step
-        return jax.jit(self._paged_decode_pure(arena),
-                       donate_argnums=arena._donate_argnums((2, 3, 4)))
+        return self._arena_jit(self._paged_decode_pure(arena), arena,
+                               (2, 3, 4))
+
+    def _arena_jit(self, fn: Callable, arena: KVArena,
+                   donate_argnums: Tuple[int, ...], n_lead: int = 1):
+        """jit one arena step whose outputs are ``(*lead, pages, state,
+        lens)``, donating the arena buffers.  Under a service mesh the
+        buffers come back with the placements they went in with (no
+        reshard, and the next call hits the same compile), and the step
+        is traced and run under the mesh so the Pallas kernels split
+        their heads over its ``model`` axis (``kernels/ops.py``)."""
+        if self.mesh is None:
+            return jax.jit(fn, donate_argnums=donate_argnums)
+        return _MeshStep(jax.jit(
+            fn, donate_argnums=donate_argnums,
+            out_shardings=(None,) * n_lead + arena.shardings()), self.mesh)
 
     def decode_cost_analysis(self, group: int = 0) -> Dict[str, Any]:
         """XLA cost analysis of the compiled fused decode step at the
@@ -1830,8 +1957,6 @@ class ServiceRuntime:
             cost = lowered.compile().cost_analysis()
         finally:
             self.decode_traces, self.prefill_traces = traces0, ptraces0
-        if isinstance(cost, (list, tuple)):   # jax version compat
-            cost = cost[0]
         return dict(cost)
 
     def _decode_group_paged(self, state: _GroupState) -> None:

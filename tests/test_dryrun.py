@@ -92,8 +92,6 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     lowered = steplib.lower_step(bundle)
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older JAX wraps the dict in a list
-        ca = ca[0]
     print(json.dumps({"ok": True, "flops": float(ca.get("flops", 0))}))
 """)
 

@@ -223,6 +223,15 @@ def test_ops_dispatch_env(monkeypatch):
         ops.default_impl()
     monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas_interpret")
     assert ops.default_impl() == "pallas_interpret"
+    # unset, the backend decides; the compiled kernel is never asked of a
+    # CPU, and never silently interpreted there
+    monkeypatch.delenv("REPRO_KERNEL_IMPL")
+    assert ops.default_impl() == ("pallas" if jax.default_backend() == "tpu"
+                                  else "ref")
+    if jax.default_backend() != "tpu":
+        monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+        with pytest.raises(ValueError, match="TPU"):
+            ops.default_impl()
 
 
 # ---------------------------------------------------------------------------

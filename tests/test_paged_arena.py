@@ -435,3 +435,53 @@ def test_simulator_paged_mode_beats_dense_copy_overhead():
     with pytest.raises(ValueError):
         run_comparison(servers, services, events, ["EPARA"],
                        dc.replace(base, serving_mode="bogus"))
+
+
+# ---------------------------------------------------------------------------
+# arena capacity fitted to device memory
+# ---------------------------------------------------------------------------
+
+def _fitted_runtime(dense_cfg, free_bytes, bs=64):
+    params = T.init(jax.random.PRNGKey(0), dense_cfg)
+    rt = ServiceRuntime(dense_cfg, params, _plan(bs=bs), max_seq_len=40,
+                        block_size=8)
+    rt._free_device_bytes = lambda: free_bytes
+    return rt
+
+
+def test_arena_capacity_fits_device_memory(dense_cfg):
+    """With a device memory limit the arena takes the plan's bs slots
+    clipped to what fits after the fused steps' temporaries (bs itself
+    stays the plan's); on CPU, with no limit, it takes bs as is."""
+    probe = KVArena(dense_cfg, T.init_cache, capacity=1, max_seq_len=40,
+                    block_size=8)
+    per_slot = probe.device_slot_bytes()
+    assert per_slot >= probe.slot_tokens * probe.token_bytes
+    rt = _fitted_runtime(dense_cfg, free_bytes=None)
+    assert rt._fit_capacity() == 64
+    rt = _fitted_runtime(dense_cfg, free_bytes=10 ** 12)
+    assert rt._fit_capacity() == 64                  # plenty: plan's bs
+    rt = _fitted_runtime(dense_cfg, free_bytes=0)
+    scratch = rt._step_scratch_bytes(probe, 8)
+    assert scratch > 0
+    budget = scratch + 8 * per_slot
+    rt = _fitted_runtime(dense_cfg, free_bytes=budget)
+    first = budget // per_slot           # before the steps' temporaries
+    cap = rt._fit_capacity()
+    assert 1 <= cap < first <= 64
+    assert cap * per_slot + rt._step_scratch_bytes(probe, cap) <= budget
+    assert rt.plan.bs == 64 and rt.decode_traces == rt.prefill_traces == 0
+    # the runtime serves through the clipped arena: admissions past the
+    # slots wait their turn instead of failing
+    for i in range(cap + 2):
+        rt.submit(GenerationRequest(rid=i, tokens=np.arange(1, 9, dtype=np.int32),
+                                    max_new_tokens=3))
+    done = rt.drain()
+    assert len(done) == cap + 2 and rt.groups[0].arena.capacity == cap
+    assert rt.total_slots() == cap
+
+
+def test_arena_capacity_fails_loudly_without_one_slot(dense_cfg):
+    rt = _fitted_runtime(dense_cfg, free_bytes=1)
+    with pytest.raises(MemoryError, match="max_seq_len=40"):
+        rt._fit_capacity()

@@ -1,5 +1,7 @@
 """Roofline machinery: the trip-count-aware HLO cost analyzer on known
 programs, collective wire factors, analytic traffic model sanity."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -38,8 +40,6 @@ def test_unrolled_matches_xla():
     c = jax.jit(f).lower(x).compile()
     cost = analyze_hlo_text(c.as_text())
     ca = c.cost_analysis()
-    if isinstance(ca, list):  # older JAX wraps the dict in a list
-        ca = ca[0]
     assert cost.flops == pytest.approx(float(ca["flops"]), rel=0.05)
 
 
@@ -88,7 +88,8 @@ ENTRY %main (a: f32[128]) -> f32[128] {
 
 
 def test_roofline_dominant_and_ratio():
-    r = Roofline(name="x", chips=4, flops_per_device=197e12,
+    r = Roofline(name="x", device_kind="TPU v5 lite", chips=4,
+                 flops_per_device=197e12,
                  bytes_per_device=819e9 * 2, collective_wire_bytes=50e9 / 2,
                  collective_counts={}, memory_stats={}, model_flops=197e12)
     assert r.compute_s == pytest.approx(1.0)
@@ -96,6 +97,9 @@ def test_roofline_dominant_and_ratio():
     assert r.collective_s == pytest.approx(0.5)
     assert r.dominant == "memory"
     assert r.useful_flops_ratio == pytest.approx(0.25)
+    # a chip without published peaks is an error, not a v5e default
+    with pytest.raises(ValueError, match="no published peaks"):
+        dataclasses.replace(r, device_kind="cpu")
 
 
 def test_model_flops_estimate_rules():

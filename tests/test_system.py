@@ -2,6 +2,7 @@
 placement -> sync -> handler -> live JAX serving — plus the launchers'
 public entry points."""
 import dataclasses
+import hashlib
 
 import jax
 import numpy as np
@@ -86,3 +87,70 @@ def test_reduced_configs_are_smoke_sized():
         assert cfg.d_model <= 512
         if cfg.family == "moe":
             assert cfg.num_experts <= 4
+
+
+def _run_py(code: str, **env_over) -> str:
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, **env_over)
+    env["PYTHONPATH"] = (os.path.abspath("src") + os.pathsep
+                         + env.get("PYTHONPATH", ""))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600, env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return out.stdout.strip().splitlines()[-1]
+
+
+def test_serve_weights_depend_on_seed_not_process():
+    """Launcher weights come from --seed and the arch id: the same in
+    every process whatever its string-hash salt, different per seed."""
+    from repro.launch.serve import init_params
+    cfg = reduced(get_config("minicpm-2b"))
+    code = ("from repro.configs import get_config, reduced\n"
+            "from repro.launch.serve import init_params\n"
+            "import hashlib, jax, numpy as np\n"
+            "p = init_params(reduced(get_config('minicpm-2b')), 7)\n"
+            "print(hashlib.sha256(b''.join(np.asarray(x).tobytes()"
+            " for x in jax.tree.leaves(p))).hexdigest())\n")
+    digests = {_run_py(code, PYTHONHASHSEED=str(h), JAX_PLATFORMS="cpu")
+               for h in (1, 2)}
+    here = hashlib.sha256(b"".join(
+        np.asarray(x).tobytes()
+        for x in jax.tree.leaves(init_params(cfg, 7)))).hexdigest()
+    assert digests == {here}
+    other = init_params(cfg, 8)
+    assert not all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(other), jax.tree.leaves(init_params(cfg, 7))))
+
+
+def test_serve_launcher_shards_over_four_devices():
+    """--pjit-decode on four (virtual CPU) devices: weights are born
+    sharded over the (1, 4) mesh's model axis, the arena with them, the
+    decode step compiles once, and the greedy tokens equal the
+    one-device run's."""
+    code = """
+import json, jax, numpy as np
+from repro.launch import serve
+argv = ["--archs", "minicpm-2b", "--servers", "1", "--requests", "3",
+        "--max-new-tokens", "4", "--prompt-len", "10,40"]
+one = serve.serve(argv)
+mp = serve.serve(argv + ["--pjit-decode"])
+p = mp.params["minicpm-2b"]
+wq = p["blocks"]["attn"]["wq"]
+print(json.dumps({
+    "rc": [one.exit_code, mp.exit_code],
+    "same": {r.rid: r.tokens.tolist() for r in one.results}
+            == {r.rid: r.tokens.tolist() for r in mp.results},
+    "traces": mp.decode_traces,
+    "wq_devices": len(wq.sharding.device_set),
+    "wq_shard": list(wq.sharding.shard_shape(wq.shape)),
+    "wq_shape": list(wq.shape)}))
+"""
+    import json
+    rec = json.loads(_run_py(
+        code, JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4"))
+    assert rec["rc"] == [0, 0] and rec["same"] and rec["traces"] == 1
+    assert rec["wq_devices"] == 4
+    assert rec["wq_shard"][-1] * 4 == rec["wq_shape"][-1]
