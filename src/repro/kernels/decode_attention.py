@@ -119,6 +119,7 @@ def decode_attention_pallas(q, k_cache, v_cache, cache_len, *, window=None,
 
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda bh, ki: (bh, 0),
@@ -243,6 +244,7 @@ def chunk_prefill_attention_pallas(q, k_cache, v_cache, start, chunk_len, *,
                                nk=nk, Tp=Tp)
     out = pl.pallas_call(
         kernel,
+        name="chunk_prefill_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda bh, ki: (bh, 0),
@@ -373,6 +375,7 @@ def paged_chunk_prefill_attention_pallas(q, k_pages, v_pages, block_tables,
                                nk=nk, Tp=Tp, q_heads=Hq)
     out = pl.pallas_call(
         kernel,
+        name="paged_chunk_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, Tp, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -511,6 +514,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
                                k_block=k_block, nk=nk, q_heads=Hq)
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, _SUB, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -629,6 +633,7 @@ def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
                                k_block=k_block, nk=nk, q_heads=Hq)
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention_int8",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, _SUB, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -732,6 +737,7 @@ def paged_chunk_prefill_attention_quant_pallas(q, k_pages, v_pages,
                                nk=nk, Tp=Tp, q_heads=Hq)
     out = pl.pallas_call(
         kernel,
+        name="paged_chunk_attention_int8",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, Tp, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -1,12 +1,12 @@
 """Observability layer: request-lifecycle tracing, metrics exposition,
 and telemetry-calibrated simulation.
 
-Three zero-dependency, host-side-only modules (enabling any of them
-cannot change emitted tokens or compile counts — asserted by
-``tests/test_obs.py``):
+Three host-side-only modules (enabling any of them cannot change emitted
+tokens or compile counts — asserted by ``tests/test_obs.py``):
 
 * ``obs.trace`` — span tracer with a bounded ring buffer and
-  Chrome-trace-event JSON export (Perfetto-loadable).
+  Chrome-trace-event JSON export (Perfetto-loadable); its round-level
+  spans are also ``jax.profiler`` annotations (``epara.*``).
 * ``obs.metrics`` — counter/gauge/histogram registry with Prometheus
   text exposition and JSONL snapshots, fed per step by the engine.
 * ``obs.calibrate`` — folds recorded telemetry back into ``SimConfig``

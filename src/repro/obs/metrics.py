@@ -293,7 +293,7 @@ class MetricsRegistry:
                         new_tokens: int) -> None:
         """Per-request latency decomposition, recorded at eviction."""
         self.histogram("ttft_seconds",
-                       "admission -> first token").observe(
+                       "submit -> first token").observe(
             max(0.0, ttft_s), service=service)
         if tpot_s is not None:
             self.histogram("tpot_seconds",

@@ -1,9 +1,9 @@
 """Span-based request-lifecycle + engine-phase tracer.
 
-Zero-dependency, host-side only: the tracer never touches a jax array or
-a compiled function, so enabling it cannot change emitted tokens or
-compile counts — it wall-clocks and annotates what the engine already
-does.  Two kinds of timelines share one bounded ring buffer:
+Host-side only: the tracer never touches a jax array or a compiled
+function, so enabling it cannot change emitted tokens or compile counts —
+it wall-clocks and annotates what the engine already does.  Its timelines
+share one bounded ring buffer:
 
 * **per-request lifecycle** — one logical thread per request id
   (``tid=str(rid)``; n>1 sampling forks get ``"rid.sample"``), with a
@@ -22,6 +22,19 @@ does.  Two kinds of timelines share one bounded ring buffer:
   (``step`` / ``evict`` / ``admit`` / ``preempt`` / ``chunk`` /
   ``fused_decode`` / ``verify`` / ``sample``), so a Perfetto track shows
   where each scheduling round's wall time went.
+* **device waits** — on ``tid="wait"``, one span per call where the host
+  blocks on the device (``decode`` / ``chunk`` / ``first_token`` /
+  ``oneshot`` / ``verify`` / ``draft`` / ``fork`` / ``park``).  The loop is
+  synchronous, so a round's ``step`` less its waits is host work.
+* **control plane** — ``ClusterSupervisor.step`` on ``pid="control"``,
+  ``tid="control"`` (``step`` holding ``publish`` and ``sync``).
+
+The round-level spans (engine phases, waits, control plane) are scoped:
+``Tracer.span`` records the ring event and, while the span is open,
+holds a ``jax.profiler.TraceAnnotation`` named ``epara.<tid>.<name>``, so
+any ``jax.profiler`` capture shows them on the host track beside the
+device's ops, on the device trace's clock.  Request lifecycle spans cross
+rounds and stay ring-only.
 
 The ring buffer (``capacity`` finished events; oldest dropped, counted
 in ``dropped``) bounds memory on long serves.  ``chrome_trace()``
@@ -33,7 +46,8 @@ invariants).
 
 A module-level ``NULL_TRACER`` no-ops every method with ``enabled =
 False`` — the engine holds it by default so the disabled layer costs one
-predicate per call site and allocates nothing.
+predicate per call site and allocates nothing (its ``span`` returns one
+shared no-op context and makes no annotation).
 """
 from __future__ import annotations
 
@@ -42,6 +56,8 @@ import json
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # event record layout (tuples, not dicts: the ring buffer holds many)
 _COMPLETE, _INSTANT = "X", "i"
@@ -61,10 +77,59 @@ class Span:
         return self.end - self.start
 
 
+class _NullSpan:
+    """The disabled ``span``: one shared context that records nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _ScopedSpan:
+    """An open ``Tracer.span``: holds the profiler annotation and, on
+    exit, records one complete event on the ring."""
+    __slots__ = ("_tr", "_pid", "_tid", "_name", "_args", "_ann", "_t0")
+
+    def __init__(self, tr: "Tracer", pid: str, tid: str, name: str,
+                 args: Dict[str, Any]):
+        self._tr, self._pid, self._tid, self._name = tr, pid, tid, name
+        self._args = args
+        self._ann = TraceAnnotation(f"epara.{tid}.{name}", **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = self._tr.clock()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self._tr.clock()
+        self._ann.__exit__(*exc)
+        self._tr._push((_COMPLETE, self._pid, self._tid, self._name,
+                        self._t0, max(t1, self._t0), self._args))
+        return False
+
+    def set(self, **args) -> None:
+        """Add args known only once the span's work is done (ring event
+        only: the annotation took its args when it opened)."""
+        self._args.update(args)
+
+
 class _NullTracer:
     """The disabled layer: every method is a no-op, ``enabled`` is
     False so call sites can skip building args entirely."""
     enabled = False
+
+    def span(self, *a, **k) -> _NullSpan:
+        return _NULL_SPAN
 
     def begin(self, *a, **k):
         pass
@@ -145,7 +210,7 @@ class Tracer:
             stack = self._stacks.get((pid, tid))
 
     def complete(self, pid: str, tid: str, name: str, start: float,
-                 end: Optional[float] = None, **args) -> None:
+                 end: Optional[float] = None, /, **args) -> None:
         """Record an already-timed span (phase timings, chunk calls)."""
         end = self.clock() if end is None else end
         self._push((_COMPLETE, pid, tid, name, start, max(end, start),
@@ -155,6 +220,15 @@ class Tracer:
                 ts: Optional[float] = None, **args) -> None:
         ts = self.clock() if ts is None else ts
         self._push((_INSTANT, pid, tid, name, ts, ts, args))
+
+    def span(self, pid: str, tid: str, name: str, /,
+             **args) -> _ScopedSpan:
+        """A scoped span for ``with``: the same complete event as
+        ``complete`` over the block, plus a ``jax.profiler``
+        ``TraceAnnotation`` named ``epara.<tid>.<name>`` while it is open
+        (a few microseconds a span, with or without a profiler running).
+        ``set`` on the returned span adds args found inside the block."""
+        return _ScopedSpan(self, pid, tid, name, args)
 
     # -- introspection / export ----------------------------------------
     def open_spans(self, pid: str, tid: str) -> List[str]:

@@ -94,6 +94,7 @@ class ParkedEntry:
     decode_start_wall: float
     admitted_s: float
     parked_s: float
+    submit_wall: float               # when the request reached the runtime
 
 
 class AdmissionController:

@@ -527,13 +527,13 @@ class KVArena:
             src[i], dst[i] = s, d
         fn = self._cow_many_fns.get(n)
         if fn is None:
-            def _copy(pages, src, dst):
+            def cow_copy_blocks(pages, src, dst):
                 # tree-mapped so a QuantPages pool copies its scale blocks
                 # together with the int8 value blocks (scales share the
                 # pools' leading (layers, blocks) layout)
                 return jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]),
                                     pages)
-            fn = jax.jit(_copy, donate_argnums=(0,),
+            fn = jax.jit(cow_copy_blocks, donate_argnums=(0,),
                          out_shardings=(None if self.mesh is None
                                         else self.shardings()[0]))
             self._cow_many_fns[n] = fn
@@ -614,7 +614,7 @@ class KVArena:
         n_blocks = self.blocks_for(max(1, prompt_len))
         fn = self._write_fns.get(n_blocks)
         if fn is None:
-            fn = jax.jit(functools.partial(self._write_prefill_impl,
+            fn = jax.jit(functools.partial(self._scatter_prefill_blocks,
                                            n_blocks=n_blocks),
                          donate_argnums=(0, 1, 2),
                          out_shardings=self.shardings())
@@ -626,8 +626,8 @@ class KVArena:
             jnp.asarray(prompt_len, jnp.int32))
         return self.slot_bytes(prompt_len)
 
-    def _write_prefill_impl(self, pages, state, lens, cache, slot, bt_row,
-                            plen, *, n_blocks):
+    def _scatter_prefill_blocks(self, pages, state, lens, cache, slot,
+                                bt_row, plen, *, n_blocks):
         leaves = jax.tree.leaves(cache)
         new_pages, new_state = list(pages), list(state)
         pi = si = 0

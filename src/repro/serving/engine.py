@@ -242,17 +242,21 @@ class _Slot:
     ``begin_decode`` when the final chunk's logits yield the first token.
     """
     __slots__ = ("req", "emitted", "done", "prefill_s", "admit_wall",
-                 "decode_start_wall", "finish_wall", "admitted_s", "steps",
-                 "slot_id", "prefilling", "consumed", "sample_idx", "spec",
-                 "draft_len")
+                 "submit_wall", "decode_start_wall", "finish_wall",
+                 "admitted_s", "steps", "slot_id", "prefilling", "consumed",
+                 "sample_idx", "spec", "draft_len")
 
     def __init__(self, req: GenerationRequest, first_token: Optional[int],
                  prefill_s: float, admit_wall: float, admitted_s: float,
                  slot_id: int = -1,
-                 decode_start_wall: Optional[float] = None):
+                 decode_start_wall: Optional[float] = None,
+                 submit_wall: Optional[float] = None):
         self.req = req
         self.prefill_s = prefill_s
         self.admit_wall = admit_wall
+        # when the request reached this runtime (its first token's wait
+        # counts from here); admission time where nothing recorded it
+        self.submit_wall = admit_wall if submit_wall is None else submit_wall
         self.finish_wall = 0.0
         self.admitted_s = admitted_s
         self.steps = 0
@@ -877,7 +881,7 @@ class ServiceRuntime:
                 n = len(s.emitted)
                 self.metrics.observe_request(
                     self.obs_name,
-                    ttft_s=max(0.0, s.decode_start_wall - s.admit_wall),
+                    ttft_s=max(0.0, s.decode_start_wall - s.submit_wall),
                     tpot_s=(res.decode_s / (n - 1)) if n > 1 else None,
                     queue_wait_s=self._queue_wait.get(s.req.rid, 0.0),
                     new_tokens=n)
@@ -1093,7 +1097,8 @@ class ServiceRuntime:
                     arena.reset_len(slot_id)
                 slot = _Slot(req, None, prefill_s=0.0,
                              admit_wall=time.perf_counter(),
-                             admitted_s=now, slot_id=slot_id)
+                             admitted_s=now, slot_id=slot_id,
+                             submit_wall=self._submit_wall.get(req.rid))
                 if hit is not None:
                     slot.consumed = hit.tokens
                 if looked:
@@ -1118,14 +1123,16 @@ class ServiceRuntime:
         else:
             cache_size = int(len(req.tokens) + req.max_new_tokens)
 
+        submit_wall = self._submit_wall.get(req.rid)
         self._obs_admitted(req, group, "prefill", oneshot=True)
         t0 = time.perf_counter()
         toks, _ = self._pad_prompts([req])
         batch = self._build_batch([req], toks)
         logits, cache = self.prefill_fn(self.params, batch, cache_size)
-        first = int(np.asarray(self._sample(
-            logits, [self._req_seed(req)], [0], [0]))[0])
-        jax.block_until_ready(logits)
+        first_dev = self._sample(logits, [self._req_seed(req)], [0], [0])
+        with self._wait("oneshot", tokens=len(req.tokens)):
+            first = int(np.asarray(first_dev)[0])
+            jax.block_until_ready(logits)
         t1 = time.perf_counter()
         self.oneshot_prefills += 1
         self.prefill_tokens_computed += len(req.tokens)
@@ -1147,7 +1154,8 @@ class ServiceRuntime:
                 state.cache = kvcache.merge([state.cache, cache])
         state.slots.append(_Slot(req, first, prefill_s=t1 - t0,
                                  admit_wall=t0, admitted_s=now,
-                                 slot_id=slot_id, decode_start_wall=t1))
+                                 slot_id=slot_id, decode_start_wall=t1,
+                                 submit_wall=submit_wall))
         tr = self.trace
         if tr.enabled:
             tid = str(req.rid)
@@ -1175,7 +1183,8 @@ class ServiceRuntime:
         arena.set_len(slot_id, entry.cache_len)
         slot = _Slot(req, None, prefill_s=entry.prefill_s,
                      admit_wall=entry.admit_wall,
-                     admitted_s=entry.admitted_s, slot_id=slot_id)
+                     admitted_s=entry.admitted_s, slot_id=slot_id,
+                     submit_wall=entry.submit_wall)
         slot.prefilling = False
         slot.emitted = list(entry.emitted)
         slot.decode_start_wall = entry.decode_start_wall
@@ -1209,13 +1218,16 @@ class ServiceRuntime:
             s.spec = False
             s.draft_len = 0
             self.spec_degraded += 1
+        with self._wait("park", tokens=len(s.emitted)):
+            cache_len = int(arena.lens[s.slot_id])
         entry = ParkedEntry(
             req=s.req, group=group,
-            blocks=[], cache_len=int(arena.lens[s.slot_id]),
+            blocks=[], cache_len=cache_len,
             emitted=list(s.emitted), consumed=s.consumed, steps=s.steps,
             prefill_s=s.prefill_s, admit_wall=s.admit_wall,
             decode_start_wall=s.decode_start_wall,
-            admitted_s=s.admitted_s, parked_s=now)
+            admitted_s=s.admitted_s, parked_s=now,
+            submit_wall=s.submit_wall)
         entry.blocks = arena.park(s.slot_id)
         state.slots.remove(s)
         self.admission.note_park(entry)
@@ -1548,11 +1560,13 @@ class ServiceRuntime:
                 if tr.enabled:
                     tr.complete(self.obs_name, str(s.req.rid),
                                 "prefill_chunk", ct0, tokens=n_valid,
-                                bucket=T)
+                                bucket=T, start=s.consumed - n_valid)
                 if s.consumed >= len(s.req.tokens):
-                    first = int(np.asarray(self._sample(
-                        logits, [self._req_seed(s.req)],
-                        [s.sample_idx], [0]))[0])
+                    first_dev = self._sample(
+                        logits, [self._req_seed(s.req)], [s.sample_idx],
+                        [0])
+                    with self._wait("first_token", tokens=n_valid):
+                        first = int(np.asarray(first_dev)[0])
                     t1 = time.perf_counter()
                     s.prefill_s += t1 - t0
                     s.begin_decode(first, t1)
@@ -1577,7 +1591,8 @@ class ServiceRuntime:
                             state.arena._block_tables[s.slot_id],
                             include_partial=False)
                 else:
-                    jax.block_until_ready(logits)
+                    with self._wait("chunk", tokens=n_valid):
+                        jax.block_until_ready(logits)
                     s.prefill_s += time.perf_counter() - t0
         return done_tokens
 
@@ -1762,7 +1777,8 @@ class ServiceRuntime:
                 dlogit_steps.append(logits)
                 d = self._sample(logits, seeds, sids, offs + (j - 1),
                                  live=live_dev, stream=STREAM_DRAFT)
-                drafts_host.append(np.asarray(d))
+                with self._wait("draft", live=len(spec_slots)):
+                    drafts_host.append(np.asarray(d))
         dlogits = jnp.stack(dlogit_steps, axis=1)          # (cap, k, V)
         dtoks = np.stack(drafts_host, axis=1).astype(np.int32)
         vtok = np.zeros((cap, k + 1), np.int32)
@@ -1779,19 +1795,17 @@ class ServiceRuntime:
                                               * arena.token_bytes)
         if self._verify_fn is None:
             self._verify_fn = self._build_verify_fn(arena)
-        tv0 = tr.clock() if tr.enabled else 0.0
-        out, n_emit, arena.pages, arena.state, arena.lens = \
-            self._verify_fn(
-                self.params, jnp.asarray(vtok), dlogits,
-                jnp.asarray(dtoks), arena.pages, arena.state, arena.lens,
-                live_dev, jnp.asarray(seeds), jnp.asarray(sids),
-                jnp.asarray(offs), arena.device_block_tables(),
-                arena.device_occupancy())
-        self.verify_launches += 1
-        if tr.enabled:
-            tr.complete(self.obs_name, "engine", "verify", tv0,
-                        slots=len(spec_slots), k=k)
-        out_h, nem = np.asarray(out), np.asarray(n_emit)
+        with self._phase("verify", slots=len(spec_slots), k=k):
+            out, n_emit, arena.pages, arena.state, arena.lens = \
+                self._verify_fn(
+                    self.params, jnp.asarray(vtok), dlogits,
+                    jnp.asarray(dtoks), arena.pages, arena.state,
+                    arena.lens, live_dev, jnp.asarray(seeds),
+                    jnp.asarray(sids), jnp.asarray(offs),
+                    arena.device_block_tables(), arena.device_occupancy())
+            self.verify_launches += 1
+        with self._wait("verify", live=len(spec_slots)):
+            out_h, nem = np.asarray(out), np.asarray(n_emit)
         for s in spec_slots:
             sid = s.slot_id
             n = int(nem[sid])
@@ -1839,10 +1853,12 @@ class ServiceRuntime:
         total = P + s.req.max_new_tokens
         shared = list(arena._block_tables[s.slot_id][:arena.blocks_for(P)])
         seed = self._req_seed(s.req)
-        first = np.asarray(self._sample(
+        first_dev = self._sample(
             jnp.broadcast_to(logits.reshape(1, -1),
                              (want, logits.shape[-1])),
-            [seed] * want, list(range(1, want + 1)), [0] * want))
+            [seed] * want, list(range(1, want + 1)), [0] * want)
+        with self._wait("fork", live=want):
+            first = np.asarray(first_dev)
         spawned = 0
         for i in range(want):
             if (state.live >= self._group_slots(state)
@@ -1852,7 +1868,8 @@ class ServiceRuntime:
             arena.set_len(sid, P)
             fork = _Slot(s.req, None, prefill_s=s.prefill_s,
                          admit_wall=s.admit_wall,
-                         admitted_s=s.admitted_s, slot_id=sid)
+                         admitted_s=s.admitted_s, slot_id=sid,
+                         submit_wall=s.submit_wall)
             fork.consumed = len(s.req.tokens)
             fork.sample_idx = i + 1
             fork.begin_decode(int(first[i]), wall)
@@ -1968,6 +1985,7 @@ class ServiceRuntime:
         seeds = np.zeros((cap,), np.uint32)
         sids = np.zeros((cap,), np.uint32)
         offs = np.zeros((cap,), np.uint32)
+        n_keys = 0          # keys the live slots attend, after the append
         spec_round: List[_Slot] = []
         for s in state.slots:
             if s.done or s.prefilling:
@@ -2000,6 +2018,7 @@ class ServiceRuntime:
             # pool is shared, so the call is unconditional.
             pos = (len(s.req.tokens) + self._extra_cache_tokens()
                    + len(s.emitted) - 1)
+            n_keys += pos + 1
             copied = arena.ensure_writable(sid, pos, 1)
             if copied:
                 self.admission_copy_bytes += (
@@ -2013,14 +2032,13 @@ class ServiceRuntime:
                     self.params, jnp.asarray(tokens), arena.pages,
                     arena.state, arena.lens, live_dev,
                     arena.device_block_tables())
-            tr = self.trace
-            ts0 = tr.clock() if tr.enabled else 0.0
-            toks = np.asarray(self._sample(
-                logits, seeds, sids, offs, live=live_dev,
-                occupancy=arena.device_occupancy()))
-            if tr.enabled:
-                tr.complete(self.obs_name, "engine", "sample", ts0,
-                            live=int(live.sum()))
+            n_live = int(live.sum())
+            with self._phase("sample", live=n_live, keys=n_keys):
+                toks_dev = self._sample(
+                    logits, seeds, sids, offs, live=live_dev,
+                    occupancy=arena.device_occupancy())
+                with self._wait("decode", live=n_live):
+                    toks = np.asarray(toks_dev)
             self.decode_steps += 1
             for slot in state.slots:
                 if slot.done or slot.prefilling or not live[slot.slot_id]:
@@ -2038,11 +2056,13 @@ class ServiceRuntime:
         cur = jnp.asarray([s.emitted[-1] if not s.done else 0
                            for s in state.slots], jnp.int32)
         logits, state.cache = self.decode_fn(self.params, cur, state.cache)
-        toks = np.asarray(self._sample(
+        toks_dev = self._sample(
             logits, [self._req_seed(s.req) for s in state.slots],
             [s.sample_idx for s in state.slots],
             [len(s.emitted) for s in state.slots],
-            live=jnp.asarray(live)))
+            live=jnp.asarray(live))
+        with self._wait("decode", live=int(live.sum())):
+            toks = np.asarray(toks_dev)
         self.decode_steps += 1
         for i, slot in enumerate(state.slots):
             if slot.done:
@@ -2088,66 +2108,64 @@ class ServiceRuntime:
     def prefix_cow_copies(self) -> int:
         return self._prefix_totals()[4]
 
-    def _phase_mark(self, name: str, start: float, **args) -> float:
-        """Emit one engine-phase complete event ending NOW and return
-        that end — the next phase's start (contiguous phase track)."""
-        end = self.trace.clock()
-        self.trace.complete(self.obs_name, "engine", name, start, end,
-                            **args)
-        return end
+    def _phase(self, name: str, **args):
+        """One engine-phase span (``tid="engine"``) for ``with``."""
+        return self.trace.span(self.obs_name, "engine", name, **args)
+
+    def _wait(self, name: str, **args):
+        """A span around a call where the host blocks on the device
+        (``tid="wait"``): a round's ``step`` less its waits is host work.
+        Asynchronous launches before it (admission scatters, copy-on-write
+        copies) are paid inside the wait that follows them."""
+        return self.trace.span(self.obs_name, "wait", name, **args)
 
     def _step_continuous(self, now: float, max_wait_s: float) -> StepStats:
-        tr = self.trace
-        t_phase = step_t0 = tr.clock() if tr.enabled else 0.0
-        copy0, whole0 = self.admission_copy_bytes, self.whole_cache_copies
-        chunkw0 = self.chunk_write_bytes
-        steps0, one0 = self.decode_steps, self.oneshot_prefills
-        draft0, ver0 = self.draft_steps, self.verify_launches
-        acc0, deg0 = self.accepted_tokens, self.spec_degraded
-        fk0, fs0 = self.forks_spawned, self.fork_shortfall
-        pfx0 = self._prefix_totals()
-        moe0 = self._moe_stats.dropped if self._moe_stats else 0.0
-        results: List[GenerationResult] = []
-        for group, state in self.groups.items():
-            results.extend(self._evict(group, state, now))
-        if tr.enabled:
-            t_phase = self._phase_mark("evict", t_phase,
-                                       evicted=len(results))
-        # admission control (inert under the "fifo" policy): learn the
-        # caller's clock, shed with verdicts, order by slack, then park a
-        # victim if the urgent head can't wait — all BEFORE compose so
-        # the freed slot goes to the strictest deadline
-        ctrl = self.admission
-        rejected: List[AdmissionReject] = []
-        preempt0, resume0 = ctrl.preemptions, ctrl.resumes
-        if ctrl.active:
-            ctrl.note_step(now)
-            ctrl.order(now)          # slack order FIRST: shed walks it
-            rejected = self._shed_rejected(now)
-            self._maybe_preempt(now)
-            if tr.enabled:
-                t_phase = self._phase_mark(
-                    "preempt", t_phase, shed=len(rejected),
-                    parked=ctrl.preemptions - preempt0)
-        admitted = self._admit(now, max_wait_s)
-        if tr.enabled:
-            t_phase = self._phase_mark("admit", t_phase, admitted=admitted)
-        chunk_tokens = 0
-        for state in self.groups.values():
-            n = self._prefill_chunks(state)
-            chunk_tokens += n
-            self._draft_chunks(state)
-            if tr.enabled:
-                t_phase = self._phase_mark("chunk", t_phase, tokens=n)
-            self._decode_group(state)
-            if tr.enabled:
-                t_phase = self._phase_mark("fused_decode", t_phase)
-        pfx1 = self._prefix_totals()
-        if tr.enabled:
-            tr.complete(self.obs_name, "engine", "step", step_t0,
-                        admitted=admitted, evicted=len(results),
-                        in_flight=self.in_flight(),
-                        pending=self.pending())
+        with self._phase("step") as step_span:
+            copy0, whole0 = self.admission_copy_bytes, self.whole_cache_copies
+            chunkw0 = self.chunk_write_bytes
+            steps0, one0 = self.decode_steps, self.oneshot_prefills
+            draft0, ver0 = self.draft_steps, self.verify_launches
+            acc0, deg0 = self.accepted_tokens, self.spec_degraded
+            fk0, fs0 = self.forks_spawned, self.fork_shortfall
+            pfx0 = self._prefix_totals()
+            moe0 = self._moe_stats.dropped if self._moe_stats else 0.0
+            results: List[GenerationResult] = []
+            with self._phase("evict") as sp:
+                for group, state in self.groups.items():
+                    results.extend(self._evict(group, state, now))
+                sp.set(evicted=len(results))
+            # admission control (inert under the "fifo" policy): learn the
+            # caller's clock, shed with verdicts, order by slack, then park
+            # a victim if the urgent head can't wait — all BEFORE compose
+            # so the freed slot goes to the strictest deadline
+            ctrl = self.admission
+            rejected: List[AdmissionReject] = []
+            preempt0, resume0 = ctrl.preemptions, ctrl.resumes
+            if ctrl.active:
+                with self._phase("preempt") as sp:
+                    ctrl.note_step(now)
+                    ctrl.order(now)      # slack order FIRST: shed walks it
+                    rejected = self._shed_rejected(now)
+                    self._maybe_preempt(now)
+                    sp.set(shed=len(rejected),
+                           parked=ctrl.preemptions - preempt0)
+            with self._phase("admit") as sp:
+                admitted = self._admit(now, max_wait_s)
+                sp.set(admitted=admitted)
+            chunk_tokens = 0
+            for state in self.groups.values():
+                with self._phase("chunk") as sp:
+                    n = self._prefill_chunks(state)
+                    chunk_tokens += n
+                    self._draft_chunks(state)
+                    sp.set(tokens=n)
+                with self._phase("fused_decode"):
+                    self._decode_group(state)
+            pfx1 = self._prefix_totals()
+            if self.trace.enabled:
+                step_span.set(admitted=admitted, evicted=len(results),
+                              in_flight=self.in_flight(),
+                              pending=self.pending())
         verdict_count = lambda v: sum(1 for r in rejected
                                       if r.verdict is v)
         return StepStats(

@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.core.categories import Outcome, Request
 from repro.core.faults import FaultEvent, FaultInjector, FaultSpec
+from repro.obs.trace import NULL_TRACER
 from .admission import AdmissionReject
 
 
@@ -377,7 +378,25 @@ class ClusterSupervisor:
         """One cluster round: fire due faults, step every alive engine,
         feed queue-time back to the handler state, run the sync round,
         and fire expired retry timeouts.  Returns True when any engine
-        made progress."""
+        made progress.  The round is traced on the control plane's own
+        timeline (``pid="control"``): ``step`` around it, ``publish`` and
+        ``sync`` inside."""
+        tr = self._span_tracer()
+        with tr.span("control", "control", "step"):
+            return self._step(now, tr)
+
+    def _span_tracer(self):
+        """The tracer the round's spans go to: the supervisor's own, else
+        the first enabled one among the runtimes it steps."""
+        if self.tracer is not None and self.tracer.enabled:
+            return self.tracer
+        for eng in self.engines.values():
+            for rt in eng.runtimes.values():
+                if rt.trace.enabled:
+                    return rt.trace
+        return NULL_TRACER
+
+    def _step(self, now: float, tr) -> bool:
         self._round += 1
         if self.injector is not None:
             self.injector.drive(now, self)
@@ -406,8 +425,10 @@ class ClusterSupervisor:
                         or stats.prefill_chunk_tokens or stats.rejected
                         or stats.verify_launches or stats.draft_steps):
                     progress = True
-        self.cp.publish_all(now)
-        self.cp.sync_step(now)
+        with tr.span("control", "control", "publish"):
+            self.cp.publish_all(now)
+        with tr.span("control", "control", "sync"):
+            self.cp.sync_step(now)
         for rec in list(self.ledger.values()):
             if rec.open and now >= rec.timeout_at:
                 self.report.offload_retries += 1

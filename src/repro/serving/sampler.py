@@ -29,6 +29,7 @@ bit-identical for greedy services by construction.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -111,6 +112,7 @@ def sample(logits, key, cfg: SamplerConfig = SamplerConfig(), *,
     return out
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "stream", "fill_token"))
 def sample_per_slot(logits, base_key, seeds, sample_ids, offsets,
                     cfg: SamplerConfig = SamplerConfig(), *,
                     stream: int = STREAM_DECODE,
@@ -121,7 +123,8 @@ def sample_per_slot(logits, base_key, seeds, sample_ids, offsets,
     offsets[i], stream)`` — a pure function of that request's identity and
     progress, so its token stream is bit-identical whether it runs alone,
     in a full batch, or across a park/resume cycle.  Greedy never touches
-    a key.
+    a key.  One compiled program per call shape (``jit_sample_per_slot``
+    in a profile), not one dispatch per operation.
     """
     if cfg.temperature <= 0.0:
         out = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -132,7 +132,8 @@ def test_parked_request_owes_only_remaining_decode():
     ctrl.note_park(ParkedEntry(
         req=req, group=0, blocks=[1, 2], emitted=[5, 6], cache_len=6,
         consumed=4, steps=2, prefill_s=0.0, admit_wall=0.0,
-        decode_start_wall=0.0, admitted_s=0.0, parked_s=2.0))
+        decode_start_wall=0.0, admitted_s=0.0, parked_s=2.0,
+        submit_wall=0.0))
     # 6 - 2 emitted = 4 remaining rounds; no prefill owed (KV is resident)
     assert ctrl.service_estimate(req) == pytest.approx(4.0)
     assert ctrl.parked_group(9) == 0

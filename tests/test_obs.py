@@ -8,6 +8,7 @@ greedy tokens bit-identical and compile counts unchanged versus obs ON —
 the tracer and registry are host-side annotators, never participants.
 """
 import dataclasses
+import glob
 import json
 import math
 import os
@@ -23,8 +24,8 @@ from conftest import toy_config
 from repro.core.allocator import ParallelPlan
 from repro.core.categories import Sensitivity, TaskCategory
 from repro.models import transformer as T
-from repro.obs import (Histogram, MetricsRegistry, ServiceTelemetry,
-                       Tracer, calibrate, merge_telemetry,
+from repro.obs import (NULL_TRACER, Histogram, MetricsRegistry,
+                       ServiceTelemetry, Tracer, calibrate, merge_telemetry,
                        parse_prometheus_text, telemetry_from_runtime,
                        telemetry_from_snapshot, telemetry_from_steps,
                        validate_chrome_trace)
@@ -139,10 +140,14 @@ def test_obs_disabled_is_byte_inert(toy):
                 rt.prefill_traces)
 
     plain = run()
-    traced = run(tracer=Tracer(), metrics=MetricsRegistry())
+    tracer = Tracer()
+    traced = run(tracer=tracer, metrics=MetricsRegistry())
     assert plain[0] == traced[0]        # bit-identical greedy tokens
     assert plain[1:] == traced[1:]      # identical compile counts
     assert plain[1] == 1                # and still exactly one decode trace
+    # ... with the scoped spans and device waits recorded
+    waits = {e[3] for e in tracer.events() if e[2] == "wait"}
+    assert {"decode", "first_token"} <= waits
 
 
 # ---------------------------------------------------------------------
@@ -330,6 +335,117 @@ def test_chrome_trace_export_and_validation(basic_run, tmp_path):
             {"name": "x", "ph": "X", "ts": 0, "pid": 1, "tid": 1}]})
 
 
+def test_scoped_span_nests_and_balances():
+    ticks = iter(range(1000))
+    tr = Tracer(clock=lambda: float(next(ticks)))
+    with tr.span("p", "engine", "step", round=1) as outer:
+        with tr.span("p", "engine", "admit") as sp:
+            sp.set(admitted=2)
+        with tr.span("p", "wait", "decode", live=3):
+            pass
+        outer.set(pending=0)
+    with pytest.raises(RuntimeError):
+        with tr.span("p", "engine", "chunk"):
+            raise RuntimeError("the span still closes")
+    roots, _ = tr.span_tree("p", "engine")
+    assert [r.name for r in roots] == ["step", "chunk"]
+    step = roots[0]
+    assert step.args == {"round": 1, "pending": 0}
+    assert [(c.name, c.args) for c in step.children] == [
+        ("admit", {"admitted": 2})]
+    _check_tree(step)
+    (wait,), _ = tr.span_tree("p", "wait")
+    assert wait.args == {"live": 3}
+    assert step.start < wait.start <= wait.end < step.end
+
+
+def test_null_tracer_span_is_a_shared_noop():
+    a = NULL_TRACER.span("p", "engine", "step", live=1)
+    assert a is NULL_TRACER.span("p", "wait", "decode")
+    with a as sp:
+        sp.set(keys=4)
+        with NULL_TRACER.span("p", "engine", "admit") as inner:
+            assert inner is sp
+
+
+def _host_annotations(log_dir):
+    """(name, start_ns, end_ns) of the ``epara.*`` host annotations of
+    the profile written under ``log_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return sorted(
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("epara."))
+
+
+def test_round_spans_are_profiler_annotations(tmp_path):
+    """A ``jax.profiler`` capture of a few supervised rounds holds the
+    engine phases, device waits and control-plane spans as host
+    annotations, one for each ring event, and each wait lies inside its
+    phase."""
+    from repro.core import EdgeCloudControlPlane, ServerSpec, ServiceSpec
+    from repro.serving.engine import EparaServingEngine
+    from repro.serving.failover import ClusterSupervisor
+    cfg, params = _toy_params()
+    cp = EdgeCloudControlPlane(
+        [ServerSpec(sid=0, num_gpus=2)],
+        {"toy": ServiceSpec("toy", flops_per_request=1e10,
+                            weights_bytes=2e8, vram_bytes=5e8,
+                            slo_latency_s=100.0)})
+    cp.run_placement({("toy", 0): 10.0})
+    tracer = Tracer()
+    rt = ServiceRuntime(cfg, params, cp.plans["toy"], block_size=16,
+                        prefill_chunk=16, tracer=tracer)
+    eng = EparaServingEngine()
+    eng.deploy("toy", rt)
+    sup = ClusterSupervisor(cp, {0: eng})
+    rng = np.random.default_rng(5)
+
+    def submit(rid, n):
+        sup.submit("toy", GenerationRequest(
+            rid=rid, tokens=rng.integers(1, 257, n).astype(np.int32),
+            max_new_tokens=3), at_server=0, now=0.0)
+
+    submit(100, 40)                      # compile every shape first
+    sup.run_until_idle()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    n0 = len(tracer.events())
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    submit(1, 40)
+    submit(2, 9)
+    for _ in range(6):
+        sup.step(0.0)
+    jax.profiler.stop_trace()
+    ring = [e for e in tracer.events()[n0:]
+            if e[2] in ("engine", "wait", "control")]
+    ann = _host_annotations(str(tmp_path))
+    names = {a[0] for a in ann}
+    assert {"epara.engine.step", "epara.engine.chunk",
+            "epara.engine.fused_decode", "epara.engine.sample",
+            "epara.wait.decode", "epara.wait.chunk", "epara.wait.first_token",
+            "epara.control.step", "epara.control.publish",
+            "epara.control.sync"} <= names
+    assert sorted(f"epara.{e[2]}.{e[3]}" for e in ring) == \
+        sorted(a[0] for a in ann)
+    phase_of = {"decode": "fused_decode", "chunk": "chunk",
+                "first_token": "chunk"}
+    for name, a0, a1 in ann:
+        if not name.startswith("epara.wait."):
+            continue
+        phase = "epara.engine." + phase_of[name.rsplit(".", 1)[1]]
+        assert any(p == phase and p0 <= a0 and a1 <= p1
+                   for p, p0, p1 in ann), name
+    for name, a0, a1 in ann:
+        if name.startswith("epara.engine."):
+            assert any(p == "epara.control.step" and p0 <= a0 and a1 <= p1
+                       for p, p0, p1 in ann), name
+
+
 # ---------------------------------------------------------------------
 # metrics: bucket math + exposition round-trips
 # ---------------------------------------------------------------------
@@ -368,6 +484,18 @@ def test_prometheus_roundtrip(basic_run):
 # ---------------------------------------------------------------------
 # calibration: telemetry -> SimConfig
 # ---------------------------------------------------------------------
+def test_ttft_counts_from_submit(toy):
+    """One slot, three requests: the later ones wait in the queue for
+    whole requests, and their time to first token includes that wait."""
+    metrics = MetricsRegistry()
+    rt = _runtime(toy, bs=1, metrics=metrics)
+    _serve(rt, _reqs(3, seed=4, max_new=6))
+    ttft = metrics.histogram("ttft_seconds").value(service="toy")
+    wait = metrics.histogram("queue_wait_seconds").value(service="toy")
+    assert ttft["count"] == wait["count"] == 3
+    assert ttft["sum"] > wait["sum"] > 0.0
+
+
 def test_calibration_steps_and_runtime_agree(spec_run):
     rt, _, _, steps = spec_run
     a = telemetry_from_steps("toy", steps, spec_k=3)
