@@ -8,8 +8,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
-from repro.kernels.quant import QuantPages, quantize
+from repro.kernels import ops, paged_pool, ref
 
 
 def _bench(fn, *args, reps=5):
@@ -61,21 +60,21 @@ def run() -> list:
     # dequantizes in-register — the serving arena's quantized hot path)
     bs, P = 32, 64 * 4 + 1                 # 4 slots x 64 blocks + trash
     Bp = 4
-    kp = jax.random.normal(key, (P, bs, Hkv, D), jnp.bfloat16)
-    vp = jax.random.normal(key, (P, bs, Hkv, D), jnp.bfloat16)
+    # natural (1 layer, pages, block, heads, D) pools in the stored layout
+    kn = jax.random.normal(key, (1, P, bs, Hkv, D), jnp.bfloat16)
+    kp = vp = paged_pool.from_natural(kn)
     bt = jnp.arange(Bp * 64, dtype=jnp.int32).reshape(Bp, 64)
     cl = jnp.full((Bp,), 64 * bs, jnp.int32)
     qp = jax.random.normal(key, (Bp, Hq, D), jnp.bfloat16)
     fp = jax.jit(lambda q, k, v: ops.paged_decode_attention(
-        q, k, v, bt, cl, impl="ref"))
+        q, k, v, bt, cl, kv_heads=Hkv, impl="ref"))
     us = _bench(fp, qp, kp, vp)
     kv_bytes = 2 * Bp * 64 * bs * Hkv * D * 2
     rows.append(("kernel/paged_decode_bf16", us,
                  f"{kv_bytes / (us * 1e-6) / 1e9:.1f}GB_s"))
-    kq = QuantPages(*quantize(kp))
-    vq = QuantPages(*quantize(vp))
+    kq = vq = paged_pool.from_natural(kn, quantized=True)
     fq = jax.jit(lambda q, k, v: ops.paged_decode_attention(
-        q, k, v, bt, cl, impl="ref"))
+        q, k, v, bt, cl, kv_heads=Hkv, impl="ref"))
     us = _bench(fq, qp, kq, vq)
     kv_bytes = 2 * Bp * 64 * bs * Hkv * (D * 1 + 4)   # int8 rows + scales
     rows.append(("kernel/paged_decode_int8", us,

@@ -21,17 +21,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import ref
+from . import paged_pool, ref
 from .decode_attention import (chunk_prefill_attention_pallas,
                                decode_attention_pallas, mask_block_tables,
-                               paged_gather_ref,
                                paged_chunk_prefill_attention_pallas,
                                paged_chunk_prefill_attention_quant_pallas,
                                paged_decode_attention_pallas,
                                paged_decode_attention_quant_pallas)
 from .flash_attention import flash_attention_pallas
 from .moe_gemm import grouped_matmul_pallas
-from .quant import QuantPages, dequantize
+from .quant import QuantPages
 from .ssd_scan import ssd_scan_pallas
 
 VALID_IMPLS = ("ref", "pallas", "pallas_interpret")
@@ -141,10 +140,48 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
         softmax_scale=softmax_scale, interpret=(impl == "pallas_interpret"))
 
 
+def _paged_ref_kv(k_pages, v_pages, block_tables, valid_len, head_dim,
+                  kv_heads, layer):
+    """The ref fallback's K/V: per-slot rows gathered through a table
+    clipped at ``valid_len`` (entries past it read the one trash page),
+    int8 pools dequantized."""
+    bt = mask_block_tables(block_tables, valid_len,
+                           paged_pool.block_size_of(k_pages),
+                           paged_pool.pages_of(k_pages) - 1)
+    return tuple(paged_pool.gather(p, bt, head_dim, kv_heads, layer=layer)
+                 for p in (k_pages, v_pages))
+
+
+def _paged_kernel(fn, head_axis: int, q, k_pages, v_pages, tail, layer,
+                  kv_heads, **kw):
+    """``fn(q, *pool arrays, *tail, layer=layer, kv_heads=..., **kw)``,
+    split over the mesh's ``model`` axis by head group: axis 1 of every
+    pool array, ``head_axis`` of the queries and of the output.  A shard
+    holds whole head groups, so its KV heads are the group size times its
+    head groups."""
+    pools = [k_pages, v_pages]
+    if isinstance(k_pages, QuantPages):
+        pools = [k_pages.values, v_pages.values, k_pages.scales,
+                 v_pages.scales]
+    group = paged_pool.geometry(pools[0], pools[2] if len(pools) == 4
+                                else None, q.shape[-1], kv_heads).group
+
+    def kernel(*args):
+        *args, layer = args
+        return fn(*args, layer=layer, kv_heads=group * args[1].shape[1],
+                  **kw)
+
+    axes = (head_axis,) + (1,) * len(pools) + (None,) * (len(tail) + 1)
+    return _split_heads(kernel, axes, head_axis)(q, *pools, *tail, layer)
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len, *,
-                           softmax_scale=None, impl: Optional[str] = None):
-    """Decode attention against the serving arena's paged KV layout — the
-    families' paged-native decode hot path.
+                           kv_heads: int, layer=0, softmax_scale=None,
+                           impl: Optional[str] = None):
+    """Decode attention against the serving arena's stacked paged pools
+    (``paged_pool`` layout) at ``layer`` — the families' paged-native
+    decode hot path.  ``kv_heads`` is the pools' KV head count (which
+    ``paged_pool.geometry`` needs to tell heads from padding lanes).
 
     ``"ref"`` gathers per-slot rows through a length-clipped block table
     (entries past ``cache_len`` route to the trash page, so the CPU
@@ -158,35 +195,18 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len, *,
     identical jnp math the in-kernel dequant reproduces.
     """
     impl = impl or default_impl()
-    if isinstance(k_pages, QuantPages):
-        if impl == "ref":
-            bs = k_pages.shape[1]
-            trash = k_pages.shape[0] - 1
-            bt = mask_block_tables(block_tables, cache_len, bs, trash)
-            k = dequantize(paged_gather_ref(k_pages.values, bt),
-                           paged_gather_ref(k_pages.scales, bt))
-            v = dequantize(paged_gather_ref(v_pages.values, bt),
-                           paged_gather_ref(v_pages.scales, bt))
-            return ref.decode_attention_ref(q, k, v, cache_len,
-                                            softmax_scale=softmax_scale)
-        kernel = functools.partial(paged_decode_attention_quant_pallas,
-                                   softmax_scale=softmax_scale,
-                                   interpret=(impl == "pallas_interpret"))
-        return _split_heads(kernel, (1, 2, 2, 2, 2, None, None), 1)(
-            q, k_pages.values, v_pages.values, k_pages.scales,
-            v_pages.scales, block_tables, cache_len)
     if impl == "ref":
-        bs, trash = k_pages.shape[1], k_pages.shape[0] - 1
-        bt = mask_block_tables(block_tables, cache_len, bs, trash)
-        k = paged_gather_ref(k_pages, bt)
-        v = paged_gather_ref(v_pages, bt)
+        k, v = _paged_ref_kv(k_pages, v_pages, block_tables, cache_len,
+                             q.shape[-1], kv_heads, layer)
         return ref.decode_attention_ref(q, k, v, cache_len,
                                         softmax_scale=softmax_scale)
-    kernel = functools.partial(paged_decode_attention_pallas,
-                               softmax_scale=softmax_scale,
-                               interpret=(impl == "pallas_interpret"))
-    return _split_heads(kernel, (1, 2, 2, None, None), 1)(
-        q, k_pages, v_pages, block_tables, cache_len)
+    fn = (paged_decode_attention_quant_pallas
+          if isinstance(k_pages, QuantPages)
+          else paged_decode_attention_pallas)
+    return _paged_kernel(fn, 1, q, k_pages, v_pages,
+                         (block_tables, cache_len), layer, kv_heads,
+                         softmax_scale=softmax_scale,
+                         interpret=(impl == "pallas_interpret"))
 
 
 def chunk_attention(q, k_cache, v_cache, start, chunk_len, *,
@@ -209,60 +229,41 @@ def chunk_attention(q, k_cache, v_cache, start, chunk_len, *,
 
 
 def paged_chunk_attention(q, k_pages, v_pages, block_tables, start,
-                          chunk_len, *, prefix_len: int = 0,
-                          softmax_scale=None, impl: Optional[str] = None):
-    """Chunk-prefill attention against the serving arena's paged KV layout
-    — the families' paged-native chunked-prefill hot path.
+                          chunk_len, *, kv_heads: int, layer=0,
+                          prefix_len: int = 0, softmax_scale=None,
+                          impl: Optional[str] = None):
+    """Chunk-prefill attention against the serving arena's stacked paged
+    pools at ``layer`` — the families' paged-native chunked-prefill hot
+    path.
 
     ``"ref"`` gathers per-slot rows through a length-clipped block table
     (every attendable position sits below ``start + chunk_len``; entries
     past it route to the trash page) and runs the jnp chunk oracle; the
     Pallas path streams K/V through the table via scalar prefetch.
     ``QuantPages`` pools dispatch to the quantized variants, same contract
-    as ``paged_decode_attention``.
+    (and ``kv_heads``) as ``paged_decode_attention``.
     """
     impl = impl or default_impl()
-    if isinstance(k_pages, QuantPages):
-        end = jnp.asarray(start, jnp.int32) + jnp.asarray(chunk_len,
-                                                          jnp.int32)
-        if impl == "ref":
-            bs = k_pages.shape[1]
-            trash = k_pages.shape[0] - 1
-            bt = mask_block_tables(block_tables, end, bs, trash)
-            k = dequantize(paged_gather_ref(k_pages.values, bt),
-                           paged_gather_ref(k_pages.scales, bt))
-            v = dequantize(paged_gather_ref(v_pages.values, bt),
-                           paged_gather_ref(v_pages.scales, bt))
-            return ref.chunk_attention_ref(q, k, v, start, chunk_len,
-                                           prefix_len=prefix_len,
-                                           softmax_scale=softmax_scale)
-        kernel = functools.partial(
-            paged_chunk_prefill_attention_quant_pallas,
-            prefix_len=prefix_len, softmax_scale=softmax_scale,
-            interpret=(impl == "pallas_interpret"))
-        return _split_heads(kernel, (2, 2, 2, 2, 2, None, None, None), 2)(
-            q, k_pages.values, v_pages.values, k_pages.scales,
-            v_pages.scales, block_tables, start, chunk_len)
     if impl == "ref":
-        bs, trash = k_pages.shape[1], k_pages.shape[0] - 1
         end = jnp.asarray(start, jnp.int32) + jnp.asarray(chunk_len,
                                                           jnp.int32)
-        bt = mask_block_tables(block_tables, end, bs, trash)
-        k = paged_gather_ref(k_pages, bt)
-        v = paged_gather_ref(v_pages, bt)
+        k, v = _paged_ref_kv(k_pages, v_pages, block_tables, end,
+                             q.shape[-1], kv_heads, layer)
         return ref.chunk_attention_ref(q, k, v, start, chunk_len,
                                        prefix_len=prefix_len,
                                        softmax_scale=softmax_scale)
-    kernel = functools.partial(paged_chunk_prefill_attention_pallas,
-                               prefix_len=prefix_len,
-                               softmax_scale=softmax_scale,
-                               interpret=(impl == "pallas_interpret"))
-    return _split_heads(kernel, (2, 2, 2, None, None, None), 2)(
-        q, k_pages, v_pages, block_tables, start, chunk_len)
+    fn = (paged_chunk_prefill_attention_quant_pallas
+          if isinstance(k_pages, QuantPages)
+          else paged_chunk_prefill_attention_pallas)
+    return _paged_kernel(fn, 2, q, k_pages, v_pages,
+                         (block_tables, start, chunk_len), layer, kv_heads,
+                         prefix_len=prefix_len, softmax_scale=softmax_scale,
+                         interpret=(impl == "pallas_interpret"))
 
 
 def paged_verify_attention(q, k_pages, v_pages, block_tables, start,
-                           chunk_len, *, prefix_len: int = 0,
+                           chunk_len, *, kv_heads: int, layer=0,
+                           prefix_len: int = 0,
                            softmax_scale=None, impl: Optional[str] = None):
     """Speculative-decoding k-token verify against the paged KV layout —
     the SAME kernel path as ``paged_chunk_attention``, restated as the
@@ -296,7 +297,8 @@ def paged_verify_attention(q, k_pages, v_pages, block_tables, start,
             f"vector (0 = row not speculating), got shape "
             f"{chunk_len.shape}")
     return paged_chunk_attention(q, k_pages, v_pages, block_tables, start,
-                                 chunk_len, prefix_len=prefix_len,
+                                 chunk_len, layer=layer, kv_heads=kv_heads,
+                                 prefix_len=prefix_len,
                                  softmax_scale=softmax_scale, impl=impl)
 
 

@@ -2,19 +2,18 @@
 
 The serving arena's page pools are the decode hot loop's working set, and
 decode is memory-bound — bytes/sec IS tokens/sec.  ``QuantPages`` packs a
-KV pool as symmetric per-token-per-head int8 with an f32 scale stored as a
-sibling array of the same leading (pool, block, row, head) layout, so:
+KV pool as symmetric per-token-per-head int8 with f32 scales held beside
+the values:
 
 * every block-index operation the arena performs (COW copies, prefix-cache
-  sharing, trash-block masking, block-table gathers) applies uniformly to
-  values and scales — the scales *travel with the blocks*;
-* the paged attention kernels read int8 tiles + an (block, 1) scale column
-  and dequantize in-register before QK/PV, never materializing a float
-  pool;
+  sharing, trash-block masking, block-table gathers) applies to values
+  and scales together — the scales *travel with the blocks*;
+* the paged attention kernels read int8 tiles plus one row of scales per
+  page and apply the scales in-register, never materializing a float
+  pool (``paged_pool`` gives the stored layout of both arrays);
 * ``QuantPages`` is a registered pytree whose ``.shape``/``.dtype`` proxy
-  the value array, so shape-reading call sites (arena classification,
-  BlockSpec construction, scan carries, pjit shardings) keep working
-  unmodified.
+  the value array, so shape-reading call sites (scan carries, pjit
+  shardings) treat it like the plain pool it replaces.
 
 Quantization format (the one both the Pallas kernels and the jnp ref
 reproduce bit-for-bit, since de/quantization is the same jnp math):
@@ -34,12 +33,12 @@ EPS = 1e-8          # zero rows quantize to zeros, never divide by zero
 
 @jax.tree_util.register_pytree_node_class
 class QuantPages:
-    """An int8 array plus per-row (last-axis-reduced) float32 scales.
+    """An int8 array plus its per-row float32 scales.
 
-    ``values.shape == (*lead, D)`` and ``scales.shape == (*lead,)`` — one
-    scale per row of the quantized axis.  Shape/dtype attributes proxy the
-    value array so existing shape-reading call sites treat a QuantPages
-    like the dense pool it replaces.
+    ``quantize`` gives ``values.shape == (*lead, D)`` and ``scales.shape
+    == (*lead,)``, one scale per row of the quantized axis; an arena pool
+    stores both in the ``paged_pool`` layout.  Shape/dtype attributes
+    proxy the value array.
     """
     __slots__ = ("values", "scales")
 
@@ -84,33 +83,3 @@ def dequantize(values, scales, dtype=jnp.float32):
     """Inverse of ``quantize`` (up to the rounding loss)."""
     out = values.astype(jnp.float32) * scales[..., None].astype(jnp.float32)
     return out.astype(dtype)
-
-
-def quantize_like(x, pool):
-    """Quantize rows for insertion into ``pool``: a ``QuantPages`` pool gets
-    (int8 rows, f32 scales); a dense pool passes through as (rows, None)."""
-    if isinstance(pool, QuantPages):
-        return quantize(x)
-    return x, None
-
-
-# ---------------------------------------------------------------------------
-# tree-aware scan-carry helpers: uniform layer indexing for dense arrays
-# (one leaf) and QuantPages (values + scales leaves) inside lax.scan bodies
-# ---------------------------------------------------------------------------
-
-def tree_index_layer(tree, i):
-    """``dynamic_index_in_dim(leaf, i, 0)`` over every array in ``tree`` —
-    a plain array is its own single leaf, so existing dense carries are
-    unchanged; a QuantPages carry indexes values and scales together."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-        tree)
-
-
-def tree_update_layer(tree, leaf, i):
-    """Inverse of :func:`tree_index_layer`: write ``leaf`` back at layer
-    ``i`` of every array in ``tree``."""
-    return jax.tree.map(
-        lambda a, sub: jax.lax.dynamic_update_index_in_dim(a, sub, i, 0),
-        tree, leaf)

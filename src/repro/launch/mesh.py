@@ -214,19 +214,19 @@ def cache_specs(mesh: Mesh, cache_shape, *,
 
 
 def arena_spec(mesh: Mesh, shape: Tuple[int, ...], *,
-               scales: bool = False) -> P:
-    """PartitionSpec for one serving-arena device buffer.  Page pools
-    ``(layers, pages, block_size, Hkv, D)`` and attention-shaped state
-    shard their head/head_dim axes over ``model`` — the same placement
-    ``cache_specs`` gives the dense cache — while the PAGE axis stays
-    replicated (the block-table page indirection must resolve locally;
-    model parallelism splits heads, not the pool).  An int8 pool's scale
-    sibling ``(layers, pages, block_size, Hkv)`` (``scales=True``) shards
-    only Hkv, the same heads as its values.  Smaller state leaves shard
-    their channel axis when divisible."""
+               pool: bool = False) -> P:
+    """PartitionSpec for one serving-arena device buffer.  A page pool
+    array (``pool=True``: values ``(layers, Hkv/G, pages, block_size,
+    W)`` or an int8 pool's scale rows ``(layers, Hkv/G, rows, Ws)``,
+    see ``kernels.paged_pool``) splits its head-group axis over
+    ``model`` — the heads ``cache_specs`` splits for the dense cache —
+    while the PAGE axis stays replicated (the block-table page
+    indirection must resolve locally; model parallelism splits heads, not
+    the pool).  Per-slot state leaves ``(layers, slots, ...)`` shard their
+    head/channel axes when divisible."""
     nd = len(shape)
-    if scales:
-        prefs: Dict[Any, List[int]] = {"model": [nd - 1]}
+    if pool:
+        prefs: Dict[Any, List[int]] = {"model": [1]}
     elif nd >= 4:
         prefs = {"model": [nd - 2, nd - 1]}
     elif nd >= 3:

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.kernels.quant import tree_index_layer, tree_update_layer
 from . import layers, transformer
 from .config import ModelConfig
 from .sharding import constrain_activation
@@ -271,12 +270,10 @@ def prefill_chunk_paged(params, cfg: ModelConfig, batch, cache,
             cv = layers.linear(memory, lp["cross_attn"]["wv"],
                                lp["cross_attn"].get("bv")).reshape(
                 B, Lk, cfg.num_kv_heads, cfg.head_dim).astype(cv.dtype)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x)
-        a, kp, vp = layers.attention_chunk_paged(
-            lp["self_attn"], cfg, xn, kp, vp, block_tables, startv,
-            chunk_len, block_size=block_size, window=window,
+        a, k_all, v_all = layers.attention_chunk_paged(
+            lp["self_attn"], cfg, xn, k_all, v_all, block_tables, startv,
+            chunk_len, block_size=block_size, layer=i, window=window,
             use_rope=False, impl=impl)
         x = x + a
         xn = layers.apply_norm(lp["ln_x"], cfg, x)
@@ -288,8 +285,6 @@ def prefill_chunk_paged(params, cfg: ModelConfig, batch, cache,
         x = x + c
         x = x + layers.mlp(lp["mlp"], cfg,
                            layers.apply_norm(lp["ln2"], cfg, x))
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), (ck, cv)
 
     (h, k, v), (ck_all, cv_all) = jax.lax.scan(
@@ -369,12 +364,10 @@ def decode_step_paged(params, cfg: ModelConfig, token, cache, block_tables,
         x, k_all, v_all = carry
         lp, i, ck, cv = xs
         x = constrain_activation(x)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x[:, None])[:, 0]
-        a, kp, vp = layers.attention_decode_paged(
-            lp["self_attn"], cfg, xn, kp, vp, block_tables, lens, live,
-            block_size=block_size, window=cfg.sliding_window,
+        a, k_all, v_all = layers.attention_decode_paged(
+            lp["self_attn"], cfg, xn, k_all, v_all, block_tables, lens, live,
+            block_size=block_size, layer=i, window=cfg.sliding_window,
             use_rope=False, impl=impl)
         x = x + a
         xn = layers.apply_norm(lp["ln_x"], cfg, x[:, None])[:, 0]
@@ -385,8 +378,6 @@ def decode_step_paged(params, cfg: ModelConfig, token, cache, block_tables,
         x = x + c
         xn = layers.apply_norm(lp["ln2"], cfg, x[:, None])[:, 0]
         x = x + layers.mlp(lp["mlp"], cfg, xn)
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = jax.lax.scan(

@@ -14,7 +14,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.quant import tree_index_layer, tree_update_layer
 from . import layers, ssm, transformer
 from .config import ModelConfig
 from .sharding import constrain_activation
@@ -114,14 +113,15 @@ def _shared_chunk(shared, cfg: ModelConfig, h, h0, k_cache, v_cache,
 
 def _shared_decode_paged(shared, cfg: ModelConfig, h_t, h0_t, k_pages,
                          v_pages, block_tables, lens, live, *, block_size,
-                         window, impl=None):
-    """Paged-native ``_shared_decode``: the application's K/V stream
-    through the block table, only the new row is written back."""
+                         layer, window, impl=None):
+    """Paged-native ``_shared_decode``: application ``layer``'s K/V stream
+    through the block table out of the stacked pools, only the new row is
+    written back."""
     xcat = jnp.concatenate([h_t, h0_t], axis=-1)
     xn = layers.apply_norm(shared["ln_a"], cfg, xcat[:, None])[:, 0]
     a, k_pages, v_pages = layers.attention_decode_paged(
         shared["attn"], cfg, xn, k_pages, v_pages, block_tables, lens,
-        live, block_size=block_size, window=window, impl=impl)
+        live, block_size=block_size, layer=layer, window=window, impl=impl)
     h_t = h_t + a
     xn = layers.apply_norm(shared["ln_m"], cfg, h_t[:, None])[:, 0]
     h_t = h_t + layers.mlp(shared["mlp"], cfg, xn)
@@ -130,14 +130,15 @@ def _shared_decode_paged(shared, cfg: ModelConfig, h_t, h0_t, k_pages,
 
 def _shared_chunk_paged(shared, cfg: ModelConfig, h, h0, k_pages, v_pages,
                         block_tables, cache_len, chunk_len, *, block_size,
-                        window, impl=None):
+                        layer, window, impl=None):
     """Paged-native ``_shared_chunk``."""
     h = constrain_activation(h)
     xcat = jnp.concatenate([h, h0], axis=-1)
     xn = layers.apply_norm(shared["ln_a"], cfg, xcat)
     a, k_pages, v_pages = layers.attention_chunk_paged(
         shared["attn"], cfg, xn, k_pages, v_pages, block_tables, cache_len,
-        chunk_len, block_size=block_size, window=window, impl=impl)
+        chunk_len, block_size=block_size, layer=layer, window=window,
+        impl=impl)
     h = h + a
     h = h + layers.mlp(shared["mlp"], cfg,
                        layers.apply_norm(shared["ln_m"], cfg, h))
@@ -322,14 +323,10 @@ def prefill_chunk_paged(params, cfg: ModelConfig, batch, cache,
         idx = g * every + jnp.arange(every)
         (h, conv_all, ssd_all), _ = jax.lax.scan(
             mamba_body, (h, conv_all, ssd_all), (gp, idx))
-        kp = tree_index_layer(k_all, g)
-        vp = tree_index_layer(v_all, g)
-        h, kp, vp = _shared_chunk_paged(params["shared"], cfg, h, h0, kp,
-                                        vp, block_tables, start, chunk_len,
-                                        block_size=block_size,
-                                        window=window, impl=impl)
-        k_all = tree_update_layer(k_all, kp, g)
-        v_all = tree_update_layer(v_all, vp, g)
+        h, k_all, v_all = _shared_chunk_paged(
+            params["shared"], cfg, h, h0, k_all, v_all, block_tables, start,
+            chunk_len, block_size=block_size, layer=g, window=window,
+            impl=impl)
         return (h, conv_all, ssd_all, k_all, v_all), None
 
     carry0 = (h0, cache["conv"], cache["ssd"], cache["attn_k"],
@@ -430,14 +427,9 @@ def decode_step_paged(params, cfg: ModelConfig, token, cache, block_tables,
         idx = g * every + jnp.arange(every)
         (h, conv_all, ssd_all), _ = jax.lax.scan(
             mamba_body, (h, conv_all, ssd_all), (gp, idx))
-        kp = tree_index_layer(k_all, g)
-        vp = tree_index_layer(v_all, g)
-        h, kp, vp = _shared_decode_paged(params["shared"], cfg, h, h0, kp,
-                                         vp, block_tables, lens, live,
-                                         block_size=block_size,
-                                         window=window, impl=impl)
-        k_all = tree_update_layer(k_all, kp, g)
-        v_all = tree_update_layer(v_all, vp, g)
+        h, k_all, v_all = _shared_decode_paged(
+            params["shared"], cfg, h, h0, k_all, v_all, block_tables, lens,
+            live, block_size=block_size, layer=g, window=window, impl=impl)
         return (h, conv_all, ssd_all, k_all, v_all), None
 
     carry0 = (h0, cache["conv"], cache["ssd"], cache["attn_k"],

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.kernels.quant import QuantPages, quantize
+from repro.kernels import paged_pool
 from .config import ModelConfig
 
 
@@ -263,39 +263,29 @@ def attention_chunk(p, cfg: ModelConfig, x, k_cache, v_cache, cache_len,
 
 
 def paged_insert_rows(pages, rows, block_tables, positions, valid, *,
-                      block_size: int):
-    """Scatter per-slot K/V rows straight into a page pool.
+                      block_size: int, layer=0):
+    """Scatter per-slot K/V rows straight into a stacked page pool.
 
-    pages: one layer's physical pool (P, block_size, Hkv, D) whose LAST
-    page is the arena's reserved trash block; rows: (B, T, Hkv, D) new
-    cache rows; positions: (B, T) absolute token positions; valid: (B, T)
-    bool — invalid rows (dead slots, chunk padding) land in the trash
-    page, so the scatter stays branch-free and shape-stable.  This is the
-    paged-native write path: one row per produced token, never the dense
-    re-scatter of the whole view.
-
-    A ``QuantPages`` pool quantizes the fresh float rows on insert (the
-    fused scale update: int8 rows land in ``values``, their per-row f32
-    scales in the sibling ``scales`` pool through the same flat scatter),
-    so the pool only ever holds quantized blocks."""
-    if isinstance(pages, QuantPages):
-        qrows, srows = quantize(rows)
-        return QuantPages(
-            paged_insert_rows(pages.values, qrows, block_tables, positions,
-                              valid, block_size=block_size),
-            paged_insert_rows(pages.scales, srows, block_tables, positions,
-                              valid, block_size=block_size))
-    P = pages.shape[0]
+    pages: the arena's pool of every layer in the ``paged_pool`` layout
+    (its LAST page is the reserved trash block), written at ``layer``;
+    rows: (B, T, Hkv, D) new cache rows; positions: (B, T) absolute token
+    positions; valid: (B, T) bool — invalid rows (dead slots, chunk
+    padding) land in the trash page, so the scatter stays branch-free and
+    shape-stable.  This is the paged-native write path: one row per
+    produced token, written in place into the stored buffer (no per-layer
+    slice of the pool, no relayout), never the dense re-scatter of the
+    whole view.  A ``QuantPages`` pool quantizes the fresh float rows on
+    insert (int8 rows to the values, their f32 scales to the scale rows).
+    """
+    trash = paged_pool.pages_of(pages) - 1
     nblk = block_tables.shape[1]
     pos = jnp.clip(positions, 0, nblk * block_size - 1)
     blk = jnp.take_along_axis(block_tables, pos // block_size, axis=1)
-    flat = blk * block_size + pos % block_size
-    flat = jnp.where(valid, flat, (P - 1) * block_size)
+    blk = jnp.where(valid, blk, trash).reshape(-1)
+    off = jnp.where(valid, pos % block_size, 0).reshape(-1)
     B, T = rows.shape[:2]
-    pf = pages.reshape(P * block_size, *pages.shape[2:])
-    pf = pf.at[flat.reshape(-1)].set(
-        rows.reshape(B * T, *rows.shape[2:]).astype(pages.dtype))
-    return pf.reshape(pages.shape)
+    return paged_pool.write_rows(pages, rows.reshape(B * T, *rows.shape[2:]),
+                                 blk, off, layer=layer)
 
 
 def _no_paged_ring(window, total_tokens: int) -> None:
@@ -307,16 +297,17 @@ def _no_paged_ring(window, total_tokens: int) -> None:
 
 def attention_decode_paged(p, cfg: ModelConfig, x_t, k_pages, v_pages,
                            block_tables, lens, live, *, block_size: int,
-                           window=None, use_rope=True, impl=None):
+                           layer=0, window=None, use_rope=True, impl=None):
     """One-token decode against the serving arena's paged KV layout.
 
-    x_t: (B, d); pages: one layer's pool (P, block_size, Hkv, D) read
-    through ``block_tables`` (B, nblk); ``lens`` (B,) counts tokens
-    already cached (the new token is written at position ``lens``).  Only
-    the new K/V row is scattered back — attention reads K/V in place via
-    ``ops.paged_decode_attention``, so the hot loop never materializes a
-    dense view.  Numerically identical to ``attention_decode`` on the
-    gathered view (same projections, rope positions and masking)."""
+    x_t: (B, d); pages: the stacked pools of every layer (``paged_pool``
+    layout), read and written at ``layer`` through ``block_tables`` (B,
+    nblk); ``lens`` (B,) counts tokens already cached (the new token is
+    written at position ``lens``).  Only the new K/V row is scattered back
+    — attention reads K/V in place via ``ops.paged_decode_attention``, so
+    the hot loop never materializes a dense view.  Numerically identical
+    to ``attention_decode`` on the gathered view (same projections, rope
+    positions and masking)."""
     B = x_t.shape[0]
     _no_paged_ring(window, block_tables.shape[1] * block_size)
     if "wqkv" in p:
@@ -335,23 +326,28 @@ def attention_decode_paged(p, cfg: ModelConfig, x_t, k_pages, v_pages,
         k_t = rope(k_t[:, None], lens[:, None], cfg.rope_theta)[:, 0]
     ok = jnp.asarray(live, bool)[:, None]
     k_pages = paged_insert_rows(k_pages, k_t[:, None], block_tables,
-                                lens[:, None], ok, block_size=block_size)
+                                lens[:, None], ok, block_size=block_size,
+                                layer=layer)
     v_pages = paged_insert_rows(v_pages, v_t[:, None], block_tables,
-                                lens[:, None], ok, block_size=block_size)
+                                lens[:, None], ok, block_size=block_size,
+                                layer=layer)
     out = ops.paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                     lens + 1, impl=impl)
+                                     lens + 1, layer=layer,
+                                     kv_heads=cfg.num_kv_heads, impl=impl)
     out = out.reshape(B, cfg.num_heads * cfg.head_dim)
     return linear(out, p["wo"]), k_pages, v_pages
 
 
 def attention_chunk_paged(p, cfg: ModelConfig, x, k_pages, v_pages,
                           block_tables, cache_len, chunk_len, *,
-                          block_size: int, window=None, prefix_len=0,
-                          use_rope=True, impl=None, verify=False):
+                          block_size: int, layer=0, window=None,
+                          prefix_len=0, use_rope=True, impl=None,
+                          verify=False):
     """Chunked-prefill attention against the paged KV layout: append a
     right-padded T-token chunk (only the first ``chunk_len`` rows real)
-    at positions ``cache_len + i`` directly into the pages, then attend
-    through the block table via ``ops.paged_chunk_attention``.  The
+    at positions ``cache_len + i`` directly into the stacked pools at
+    ``layer``, then attend through the block table via
+    ``ops.paged_chunk_attention``.  The
     multi-token sibling of ``attention_decode_paged`` (and the paged
     mirror of ``attention_chunk``).
 
@@ -376,12 +372,13 @@ def attention_chunk_paged(p, cfg: ModelConfig, x, k_pages, v_pages,
         k_t = rope(k_t, positions, cfg.rope_theta)
     valid = jnp.arange(T)[None] < chunk_len[:, None]
     k_pages = paged_insert_rows(k_pages, k_t, block_tables, positions,
-                                valid, block_size=block_size)
+                                valid, block_size=block_size, layer=layer)
     v_pages = paged_insert_rows(v_pages, v_t, block_tables, positions,
-                                valid, block_size=block_size)
+                                valid, block_size=block_size, layer=layer)
     attend = ops.paged_verify_attention if verify else \
         ops.paged_chunk_attention
     out = attend(q, k_pages, v_pages, block_tables, cache_len, chunk_len,
+                 layer=layer, kv_heads=cfg.num_kv_heads,
                  prefix_len=prefix_len, impl=impl)
     out = out.reshape(B, T, cfg.num_heads * cfg.head_dim)
     return linear(out, p["wo"]), k_pages, v_pages

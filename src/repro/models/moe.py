@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.kernels.quant import tree_index_layer, tree_update_layer
 from . import layers, transformer
 from .config import ModelConfig
 from .sharding import constrain_activation
@@ -308,18 +307,14 @@ def prefill_chunk_paged(params, cfg: ModelConfig, batch, cache,
         x, k_all, v_all = carry
         lp, i = xs
         x = constrain_activation(x)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x)
-        a, kp, vp = layers.attention_chunk_paged(
-            lp["attn"], cfg, xn, kp, vp, block_tables, start, chunk_len,
-            block_size=block_size, window=window, impl=impl)
+        a, k_all, v_all = layers.attention_chunk_paged(
+            lp["attn"], cfg, xn, k_all, v_all, block_tables, start, chunk_len,
+            block_size=block_size, layer=i, window=window, impl=impl)
         x = x + a
         m, _ = moe_mlp(lp["moe"], cfg,
                        layers.apply_norm(lp["ln2"], cfg, x), impl=impl)
         x = x + m
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = jax.lax.scan(
@@ -348,18 +343,15 @@ def verify_step_paged(params, cfg: ModelConfig, batch, cache, block_tables,
         x, k_all, v_all = carry
         lp, i = xs
         x = constrain_activation(x)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x)
-        a, kp, vp = layers.attention_chunk_paged(
-            lp["attn"], cfg, xn, kp, vp, block_tables, start, chunk_len,
-            block_size=block_size, window=window, impl=impl, verify=True)
+        a, k_all, v_all = layers.attention_chunk_paged(
+            lp["attn"], cfg, xn, k_all, v_all, block_tables, start, chunk_len,
+            block_size=block_size, layer=i, window=window, impl=impl,
+            verify=True)
         x = x + a
         m, _ = moe_mlp(lp["moe"], cfg,
                        layers.apply_norm(lp["ln2"], cfg, x), impl=impl)
         x = x + m
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = jax.lax.scan(
@@ -434,17 +426,13 @@ def decode_step_paged(params, cfg: ModelConfig, token, cache, block_tables,
         x, k_all, v_all = carry
         lp, i = xs
         x = constrain_activation(x)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x[:, None])[:, 0]
-        a, kp, vp = layers.attention_decode_paged(
-            lp["attn"], cfg, xn, kp, vp, block_tables, lens, live,
-            block_size=block_size, window=window, impl=impl)
+        a, k_all, v_all = layers.attention_decode_paged(
+            lp["attn"], cfg, xn, k_all, v_all, block_tables, lens, live,
+            block_size=block_size, layer=i, window=window, impl=impl)
         x = x + a
         xn = layers.apply_norm(lp["ln2"], cfg, x[:, None])[:, 0]
         x = x + _moe_mlp_single(lp["moe"], cfg, xn, impl=impl)
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = jax.lax.scan(

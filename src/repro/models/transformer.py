@@ -13,7 +13,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.quant import tree_index_layer, tree_update_layer
 
 from . import layers
 from .config import ModelConfig
@@ -220,11 +219,12 @@ def prefill_chunk(params, cfg: ModelConfig, batch, cache, *, chunk_len,
 def prefill_chunk_paged(params, cfg: ModelConfig, batch, cache,
                         block_tables, *, chunk_len, block_size, impl=None):
     """Paged-native chunked prefill: the cache's ``k``/``v`` are the
-    arena's PAGE POOLS ``(layers, pages, block_size, Hkv, D)`` read
+    arena's stacked PAGE POOLS (``kernels.paged_pool`` layout) read
     through ``block_tables`` (B, nblk), and ``len`` is the per-slot (B,)
-    start offset.  The chunk's K/V rows scatter straight into the pages
-    (``layers.attention_chunk_paged``) — no dense view is ever gathered
-    or re-scattered.  Numerically equivalent to ``prefill_chunk`` on the
+    start offset.  Each layer reads and writes the stacked pools at its
+    own index: the chunk's K/V rows scatter straight into the pages
+    (``layers.attention_chunk_paged``) — no layer slice, dense view or
+    re-scatter.  Numerically equivalent to ``prefill_chunk`` on the
     gathered view."""
     tokens = batch["tokens"]
     window = _window(cfg)
@@ -235,19 +235,13 @@ def prefill_chunk_paged(params, cfg: ModelConfig, batch, cache,
         x, k_all, v_all = carry
         lp, i = xs
         x = constrain_activation(x)
-        # tree-aware layer indexing: QuantPages pools (int8 + scales)
-        # index/update both leaves together, dense pools are unchanged
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x)
-        h, kp, vp = layers.attention_chunk_paged(
-            lp["attn"], cfg, xn, kp, vp, block_tables, start, chunk_len,
-            block_size=block_size, window=window, impl=impl)
+        h, k_all, v_all = layers.attention_chunk_paged(
+            lp["attn"], cfg, xn, k_all, v_all, block_tables, start, chunk_len,
+            block_size=block_size, layer=i, window=window, impl=impl)
         x = x + h
         x = x + layers.mlp(lp["mlp"], cfg,
                            layers.apply_norm(lp["ln2"], cfg, x))
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = jax.lax.scan(
@@ -280,17 +274,14 @@ def verify_step_paged(params, cfg: ModelConfig, batch, cache, block_tables,
         x, k_all, v_all = carry
         lp, i = xs
         x = constrain_activation(x)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x)
-        h, kp, vp = layers.attention_chunk_paged(
-            lp["attn"], cfg, xn, kp, vp, block_tables, start, chunk_len,
-            block_size=block_size, window=window, impl=impl, verify=True)
+        h, k_all, v_all = layers.attention_chunk_paged(
+            lp["attn"], cfg, xn, k_all, v_all, block_tables, start, chunk_len,
+            block_size=block_size, layer=i, window=window, impl=impl,
+            verify=True)
         x = x + h
         x = x + layers.mlp(lp["mlp"], cfg,
                            layers.apply_norm(lp["ln2"], cfg, x))
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = jax.lax.scan(
@@ -335,12 +326,13 @@ def decode_step(params, cfg: ModelConfig, token, cache, impl=None):
 
 def decode_step_paged(params, cfg: ModelConfig, token, cache, block_tables,
                       live, *, block_size, impl=None):
-    """Paged-native fused decode: cache ``k``/``v`` are the arena PAGE
-    POOLS ``(layers, pages, block_size, Hkv, D)``, ``len`` the per-slot
-    (B,) lengths.  Attention reads K/V in place through ``block_tables``
-    and writes back only each live slot's ONE new row — the O(capacity x
-    slot_tokens x layers) dense materialize/re-scatter round trip of the
-    gather path never happens.  ``live`` masks dead/prefilling slots:
+    """Paged-native fused decode: cache ``k``/``v`` are the arena's
+    stacked PAGE POOLS (``kernels.paged_pool`` layout), ``len`` the
+    per-slot (B,) lengths.  Each layer reads K/V in place at its own
+    index of the stacked pools through ``block_tables`` and writes back
+    only each live slot's ONE new row — the O(capacity x slot_tokens x
+    layers) dense materialize/re-scatter round trip of the gather path
+    never happens.  ``live`` masks dead/prefilling slots:
     their row writes route to the trash page and their lengths hold."""
     B = token.shape[0]
     window = _window(cfg)
@@ -352,17 +344,13 @@ def decode_step_paged(params, cfg: ModelConfig, token, cache, block_tables,
         x, k_all, v_all = carry
         lp, i = xs
         x = constrain_activation(x)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x[:, None])[:, 0]
-        h, kp, vp = layers.attention_decode_paged(
-            lp["attn"], cfg, xn, kp, vp, block_tables, lens, live,
-            block_size=block_size, window=window, impl=impl)
+        h, k_all, v_all = layers.attention_decode_paged(
+            lp["attn"], cfg, xn, k_all, v_all, block_tables, lens, live,
+            block_size=block_size, layer=i, window=window, impl=impl)
         x = x + h
         xn = layers.apply_norm(lp["ln2"], cfg, x[:, None])[:, 0]
         x = x + layers.mlp(lp["mlp"], cfg, xn)
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = jax.lax.scan(

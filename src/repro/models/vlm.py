@@ -14,7 +14,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.quant import tree_index_layer, tree_update_layer
 from . import layers, transformer
 from .config import ModelConfig
 from .sharding import constrain_activation
@@ -142,18 +141,14 @@ def prefill_chunk_paged(params, cfg: ModelConfig, batch, cache,
         x, k_all, v_all = carry
         lp, i = xs
         x = constrain_activation(x)
-        kp = tree_index_layer(k_all, i)
-        vp = tree_index_layer(v_all, i)
         xn = layers.apply_norm(lp["ln1"], cfg, x)
-        a, kp, vp = layers.attention_chunk_paged(
-            lp["attn"], cfg, xn, kp, vp, block_tables, start, eff_chunk,
-            block_size=block_size, window=window, prefix_len=prefix,
+        a, k_all, v_all = layers.attention_chunk_paged(
+            lp["attn"], cfg, xn, k_all, v_all, block_tables, start, eff_chunk,
+            block_size=block_size, layer=i, window=window, prefix_len=prefix,
             impl=impl)
         x = x + a
         x = x + layers.mlp(lp["mlp"], cfg,
                            layers.apply_norm(lp["ln2"], cfg, x))
-        k_all = tree_update_layer(k_all, kp, i)
-        v_all = tree_update_layer(v_all, vp, i)
         return (x, k_all, v_all), None
 
     (h, k, v), _ = jax.lax.scan(

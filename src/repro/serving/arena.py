@@ -13,6 +13,10 @@ bytes and each batch-size change retraces the fused decode step under XLA.
   slot owns a row of a ``(capacity, blocks_per_slot)`` **block table**
   mapping logical block -> physical block (a reserved trash block absorbs
   writes from unoccupied slots, so the fused step needs no branches);
+* every pool is **stored in the layout the paged kernels read**
+  (``kernels.paged_pool``: KV heads packed to 128-lane rows, int8 scales
+  in unpadded lane rows), so a step hands the stored buffer to the kernel
+  and scatters new rows into it in place;
 * **admission writes pages in place** (``alloc`` + ``write_prefill``
   scatter exactly the new request's pages and per-slot state — the live
   batch is never touched);
@@ -49,7 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.quant import QuantPages, dequantize, quantize
+from repro.kernels import paged_pool
+from repro.kernels.quant import QuantPages
 from repro.launch import mesh as meshlib
 
 Cache = Any  # pytree of arrays
@@ -57,10 +62,6 @@ Cache = Any  # pytree of arrays
 _LEN, _PAGED, _STATE = "len", "paged", "state"
 
 VALID_KV_DTYPES = ("bf16", "int8")
-
-
-def _is_quant(pool) -> bool:
-    return isinstance(pool, QuantPages)
 
 
 def _is_len_leaf(shape: Tuple[int, ...], dtype) -> bool:
@@ -146,25 +147,33 @@ class KVArena:
                 self._paged_shapes.append(a.shape)
 
         # -- device state --------------------------------------------------
-        P1 = self.pool_blocks + 1                 # +1 trash block
+        # page pools are stored in the layout the paged kernels read
+        # (``paged_pool``): (layers, Hkv/G, pages, block_size, W), G KV
+        # heads of the per-device count to a row of whole 128-lane tiles
         self.pages: List[Any] = []
         self._quantized: List[bool] = []          # per paged leaf
+        self._pool_dims: List[Tuple[int, int, int, int]] = []  # A0,Hkv,D,G
+        self._paged_dtypes: List[Any] = []        # the leaves' own dtypes
         self.state: List[jnp.ndarray] = []
+        n_model = meshlib.axis_size(mesh, "model") if mesh is not None else 1
         for i, tag in enumerate(self._tags):
             if tag == _PAGED:
                 A0, _, _, *rest = lo_leaves[i].shape
-                quant = (self.kv_dtype == "int8" and len(rest) >= 1
-                         and jnp.issubdtype(self._dtypes[i], jnp.floating))
-                self._quantized.append(quant)
-                if quant:
-                    self.pages.append(QuantPages(
-                        self._zeros((A0, P1, self.block_size, *rest),
-                                    jnp.int8),
-                        self._zeros((A0, P1, self.block_size, *rest[:-1]),
-                                    jnp.float32, scales=True)))
-                else:
-                    self.pages.append(self._zeros(
-                        (A0, P1, self.block_size, *rest), self._dtypes[i]))
+                if len(rest) != 2:
+                    raise ValueError(
+                        f"paged leaf {lo_leaves[i].shape} is not "
+                        f"(layers, batch, seq, kv_heads, head_dim)")
+                Hkv, D = rest
+                local = Hkv // n_model if Hkv % n_model == 0 else Hkv
+                self._pool_dims.append(
+                    (A0, Hkv, D, paged_pool.head_group(D, local)))
+                self._quantized.append(
+                    self.kv_dtype == "int8"
+                    and jnp.issubdtype(self._dtypes[i], jnp.floating))
+                self._paged_dtypes.append(self._dtypes[i])
+                self.pages.append(jax.tree.map(   # +1 trash block
+                    lambda a: self._zeros(a.shape, a.dtype, pool=True),
+                    self._pool_specs(len(self.pages), self.pool_blocks + 1)))
             elif tag == _STATE:
                 A0, _, *rest = lo_leaves[i].shape
                 self.state.append(self._zeros((A0, self.capacity, *rest),
@@ -211,10 +220,7 @@ class KVArena:
         # fixed per-slot state footprint (allocator-style accounting).  A
         # quantized leaf counts 1 byte per value plus its f32 per-row scale
         self.token_bytes = 0
-        paged_dtypes = [self._dtypes[i] for i, t in enumerate(self._tags)
-                        if t == _PAGED]
-        self._paged_dtypes = paged_dtypes
-        for s, d, q in zip(self._paged_shapes, paged_dtypes,
+        for s, d, q in zip(self._paged_shapes, self._paged_dtypes,
                            self._quantized):
             if q:
                 self.token_bytes += int(np.prod([s[0], *s[3:]]))      # int8
@@ -228,12 +234,35 @@ class KVArena:
                             (self._dtypes[i] for i, t in
                              enumerate(self._tags) if t == _STATE)))
 
-    def _zeros(self, shape, dtype, *, scales: bool = False):
+    def _zeros(self, shape, dtype, *, pool: bool = False):
         if self.mesh is None:
             return jnp.zeros(shape, dtype)
-        spec = meshlib.arena_spec(self.mesh, tuple(shape), scales=scales)
+        spec = meshlib.arena_spec(self.mesh, tuple(shape), pool=pool)
         return jnp.zeros(shape, dtype,
                          device=jax.sharding.NamedSharding(self.mesh, spec))
+
+    def _pool_specs(self, i: int, pages: int):
+        """Paged leaf ``i``'s pool at ``pages`` physical pages, as
+        ``ShapeDtypeStruct``s: one array, or a ``QuantPages`` of two."""
+        A0, Hkv, D, G = self._pool_dims[i]
+        dtype = jnp.int8 if self._quantized[i] else self._paged_dtypes[i]
+        values = jax.ShapeDtypeStruct(
+            paged_pool.value_shape(A0, pages, self.block_size, Hkv, D, G),
+            dtype)
+        if not self._quantized[i]:
+            return values
+        return QuantPages(values, jax.ShapeDtypeStruct(
+            paged_pool.scale_shape(A0, pages, self.block_size, Hkv, G),
+            jnp.float32))
+
+    def pool_structs(self, pages: int) -> List[Any]:
+        """The page pools at ``pages`` physical pages (trash page
+        included), placed like the live pools: what a step compiled for
+        another capacity takes, allocated nowhere."""
+        return [jax.tree.map(lambda s, a: jax.ShapeDtypeStruct(
+                    s.shape, s.dtype, sharding=a.sharding),
+                    self._pool_specs(i, pages), pool)
+                for i, pool in enumerate(self.pages)]
 
     def shardings(self) -> Optional[Tuple[Any, Any, Any]]:
         """``(pages, state, lens)`` placements under a mesh (``None``
@@ -528,11 +557,9 @@ class KVArena:
         fn = self._cow_many_fns.get(n)
         if fn is None:
             def cow_copy_blocks(pages, src, dst):
-                # tree-mapped so a QuantPages pool copies its scale blocks
-                # together with the int8 value blocks (scales share the
-                # pools' leading (layers, blocks) layout)
-                return jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]),
-                                    pages)
+                # a QuantPages pool copies its scales with the int8 values
+                return [paged_pool.copy_pages(p, src, dst, dims[2], dims[1])
+                        for p, dims in zip(pages, self._pool_dims)]
             fn = jax.jit(cow_copy_blocks, donate_argnums=(0,),
                          out_shardings=(None if self.mesh is None
                                         else self.shardings()[0]))
@@ -641,14 +668,8 @@ class KVArena:
                 A0, _, S, *rest = leaf.shape
                 blocks = leaf[:, 0, :n_blocks * self.block_size].reshape(
                     A0, n_blocks, self.block_size, *rest)
-                if _is_quant(pages[pi]):
-                    qv, qs = quantize(blocks)
-                    new_pages[pi] = QuantPages(
-                        pages[pi].values.at[:, bt_row].set(qv),
-                        pages[pi].scales.at[:, bt_row].set(qs))
-                else:
-                    new_pages[pi] = pages[pi].at[:, bt_row].set(
-                        blocks.astype(pages[pi].dtype))
+                new_pages[pi] = paged_pool.write_pages(pages[pi], blocks,
+                                                       bt_row)
                 pi += 1
             elif tag == _STATE:
                 new_state[si] = state[si].at[:, slot].set(
@@ -675,17 +696,10 @@ class KVArena:
         and scales through the same table and dequantizes to the leaf's
         original dtype — the fallback sees exactly the float view the
         quantized kernels compute in-register."""
-        B = block_tables.shape[0]
-        out = []
-        for p, dt in zip(pages, self._paged_dtypes):
-            A0, _, bs, *rest = p.shape
-            if _is_quant(p):
-                g = dequantize(p.values[:, block_tables],
-                               p.scales[:, block_tables], dt)
-            else:
-                g = p[:, block_tables]    # (A0, B, nblk, bs, *rest)
-            out.append(g.reshape(A0, B, self.slot_tokens, *rest))
-        return out
+        return [paged_pool.gather(p, block_tables, dims[2], dims[1],
+                                  dtype=dt)
+                for p, dt, dims in zip(pages, self._paged_dtypes,
+                                       self._pool_dims)]
 
     def assemble(self, dense: Sequence[jnp.ndarray],
                  state: Sequence[jnp.ndarray],
@@ -741,34 +755,19 @@ class KVArena:
         pos = jnp.clip(lens[:, None] + offs[None], 0,
                        self.slot_tokens - 1)              # (cap, T)
         blk = jnp.take_along_axis(block_tables, pos // bs, axis=1)
-        flat = blk * bs + pos % bs
         ok = live[:, None]
         if valid_tokens is not None:
             ok = ok & (offs[None] < valid_tokens[:, None])
-        flat = jnp.where(ok, flat, self.trash_block * bs).reshape(-1)
+        blk = jnp.where(ok, blk, self.trash_block).reshape(-1)
+        off = jnp.where(ok, pos % bs, 0).reshape(-1)
         out = []
         for p, d in zip(pages, dense_new):
-            A0, P1, _, *rest = p.shape
+            A0, _, _, *rest = d.shape
             idx = pos.reshape(1, cap, n_tokens, *([1] * len(rest)))
             row = jnp.take_along_axis(d, idx, axis=2)     # (A0, cap, T, ...)
-            if _is_quant(p):
-                # fused scale update: the fresh float rows quantize on
-                # insert; int8 rows and their scales land through the same
-                # flat scatter, so the pool only ever holds quantized blocks
-                qv, qs = quantize(row)
-                pfv = p.values.reshape(A0, P1 * bs, *rest)
-                pfv = pfv.at[:, flat].set(
-                    qv.reshape(A0, cap * n_tokens, *rest))
-                pfs = p.scales.reshape(A0, P1 * bs, *rest[:-1])
-                pfs = pfs.at[:, flat].set(
-                    qs.reshape(A0, cap * n_tokens, *rest[:-1]))
-                out.append(QuantPages(pfv.reshape(p.values.shape),
-                                      pfs.reshape(p.scales.shape)))
-                continue
-            pf = p.reshape(A0, P1 * bs, *rest)
-            pf = pf.at[:, flat].set(
-                row.reshape(A0, cap * n_tokens, *rest).astype(p.dtype))
-            out.append(pf.reshape(p.shape))
+            # a QuantPages pool quantizes the fresh float rows on insert
+            out.append(paged_pool.write_rows(
+                p, row.reshape(A0, cap * n_tokens, *rest), blk, off))
         return out
 
     def merge_state(self, state: Sequence[jnp.ndarray],

@@ -86,6 +86,7 @@ eviction hook once a session has no queued or in-flight requests left.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -938,6 +939,8 @@ class ServiceRuntime:
         free //= sum(1 for g in self.groups.values() if g.arena is None)
         probe = self._new_arena(1)
         per_slot = probe.device_slot_bytes()
+        if self.speculate_k > 0:     # a draft arena pairs every slot
+            per_slot += self._new_draft_arena().device_slot_bytes()
         # the temporaries at ``cap`` slots bound those of any smaller
         # count, so ``min(cap, fit)`` fits; when they leave no slot, or
         # the compiler itself finds the program too big for the device,
@@ -974,40 +977,92 @@ class ServiceRuntime:
             return None
         return min(st["bytes_limit"] - st["bytes_in_use"] for st in stats)
 
+    def _new_draft_arena(self, capacity: int = 1) -> KVArena:
+        """A draft-model arena.  Its KV stays native precision: the
+        proposals are re-scored by the target anyway, but int8 would
+        change WHICH tokens get proposed run-to-run."""
+        return KVArena(self.draft_cfg, self.draft_api.init_cache,
+                       capacity=capacity, max_seq_len=self.max_seq_len,
+                       block_size=self.block_size, mesh=self.mesh,
+                       kv_dtype="bf16")
+
     def _step_scratch_bytes(self, probe: KVArena, capacity: int) -> int:
-        """Device bytes a fused decode or chunk call allocates beyond its
-        donated arguments at ``capacity`` slots (temporaries plus
-        un-aliased outputs, from ``memory_analysis`` of the compiled
-        steps), compiled against ``probe``'s layout without allocating
-        the full arena."""
-        bps = probe.blocks_per_slot
+        """Device bytes the serving programs need beside the arena at
+        ``capacity`` slots, from ``memory_analysis`` of the programs
+        compiled against ``probe``'s layout without allocating the full
+        arena: the code of every program that stays loaded (the fused
+        decode step, a chunk step per bucket, the samplers and, when
+        speculating, the draft decode and verify steps), plus the most
+        any one call allocates beyond its donated arguments (temporaries
+        and un-aliased outputs, with the logits it samples from still
+        live) — the one-shot prefill of a longest prompt included where
+        prompts are not chunked."""
+        cap, bps = capacity, probe.blocks_per_slot
 
-        def grown(a, n):
-            return jax.ShapeDtypeStruct((a.shape[0], n, *a.shape[2:]),
-                                        a.dtype, sharding=a.sharding)
+        def arena_args(arena):
+            grown = lambda a: jax.ShapeDtypeStruct(
+                (a.shape[0], cap, *a.shape[2:]), a.dtype,
+                sharding=a.sharding)
+            return (arena.pool_structs(cap * bps + 1),
+                    [grown(st) for st in arena.state],
+                    jax.ShapeDtypeStruct((cap,), jnp.int32,
+                                         sharding=arena.lens.sharding))
 
-        pages = jax.tree.map(lambda p: grown(p, capacity * bps + 1),
-                             probe.pages)
-        state = [grown(st, capacity) for st in probe.state]
-        lens = jax.ShapeDtypeStruct((capacity,), jnp.int32,
-                                    sharding=probe.lens.sharding)
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-        T = self.chunk_buckets[-1]
-        counters = (self.decode_traces, self.prefill_traces)
+        u32 = jax.ShapeDtypeStruct((cap,), jnp.uint32)
+        flags = jax.ShapeDtypeStruct((cap,), bool)
+        counters = ("decode_traces", "prefill_traces", "verify_traces",
+                    "draft_decode_traces")
+        saved = [getattr(self, c) for c in counters]
+        pools = arena_args(probe)
+        progs = []               # (lowered, resident copies, live bytes)
         try:
-            steps = [self._build_paged_decode_fn(probe).lower(
-                self.params, i32(capacity), pages, state, lens,
-                jax.ShapeDtypeStruct((capacity,), bool),
-                i32(capacity, bps))]
+            decode = self._build_paged_decode_fn(probe).lower(
+                self.params, i32(cap), *pools, flags, i32(cap, bps))
+            logits = decode.out_info[0]
+            nbytes = lambda a: math.prod(a.shape) * a.dtype.itemsize
+            progs.append((decode, 1, 0))
             if self.chunked_prefill:
-                steps.append(self._build_chunk_fn(probe, T, False).lower(
-                    self.params, i32(1, T), None, pages, state, lens,
-                    i32(), i32(bps), i32()))
-            mems = [s.compile().memory_analysis() for s in steps]
+                T = self.chunk_buckets[-1]
+                progs.append((self._build_chunk_fn(probe, T, False).lower(
+                    self.params, i32(1, T), None, *pools, i32(), i32(bps),
+                    i32()), len(self.chunk_buckets), 0))
+            else:
+                # one program per prompt length: the longest one's
+                # temporaries bound the others'
+                n = probe.slot_tokens - self._extra_cache_tokens()
+                if (n > 1 and hasattr(self.prefill_fn, "lower")
+                        and self.cfg.family not in ("audio", "vlm")):
+                    progs.append((self.prefill_fn.lower(
+                        self.params, {"tokens": i32(1, n - 1)}, n), 1, 0))
+            # decode's sampler and a first token's (or a draft's)
+            progs.append((sample_per_slot.lower(
+                logits, self._key, u32, u32, u32, self.sampler,
+                live=flags, occupancy=flags), 2, nbytes(logits)))
+            if self.speculate_k > 0:
+                k = self.speculate_k
+                draft = self._new_draft_arena()
+                progs.append((self._arena_jit(self._paged_decode_pure(
+                    draft, api=self.draft_api, cfg=self.draft_cfg,
+                    native=True, counter="draft_decode_traces"), draft,
+                    (2, 3, 4)).lower(
+                        self.draft_params, i32(cap), *arena_args(draft),
+                        flags, i32(cap, bps)), 1, 0))
+                dlogits = jax.ShapeDtypeStruct(
+                    (cap, k, logits.shape[-1]), logits.dtype)
+                progs.append((self._build_verify_fn(probe).lower(
+                    self.params, i32(cap, k + 1), dlogits, i32(cap, k),
+                    *pools, flags, u32, u32, u32, i32(cap, bps), flags),
+                    1, nbytes(dlogits)))
+            mems = [(lo.compile().memory_analysis(), n, live)
+                    for lo, n, live in progs]
         finally:
-            self.decode_traces, self.prefill_traces = counters
-        return max(m.temp_size_in_bytes + m.output_size_in_bytes
-                   - m.alias_size_in_bytes for m in mems)
+            for c, v in zip(counters, saved):
+                setattr(self, c, v)
+        code = sum(m.generated_code_size_in_bytes * n for m, n, _ in mems)
+        return code + max(m.temp_size_in_bytes + m.output_size_in_bytes
+                          - m.alias_size_in_bytes + live
+                          for m, _, live in mems)
 
     def _ensure_arena(self, state: _GroupState) -> KVArena:
         if state.arena is None:
@@ -1608,15 +1663,8 @@ class ServiceRuntime:
 
     def _ensure_draft(self, state: _GroupState) -> KVArena:
         if state.draft is None:
-            state.draft = KVArena(
-                self.draft_cfg, self.draft_api.init_cache,
-                capacity=state.arena.capacity,   # slot ids pair up
-                max_seq_len=self.max_seq_len, block_size=self.block_size,
-                mesh=self.mesh,
-                kv_dtype="bf16")   # draft KV stays native precision: its
-            #                        proposals are re-scored by the target
-            #                        anyway, but int8 would change WHICH
-            #                        tokens get proposed run-to-run
+            # slot ids pair up with the target arena's
+            state.draft = self._new_draft_arena(state.arena.capacity)
         return state.draft
 
     def _enable_spec(self, state: _GroupState, s: _Slot) -> None:

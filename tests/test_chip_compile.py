@@ -11,12 +11,14 @@ process may load the TPU compiler library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import paged_pool
 from repro.kernels.decode_attention import (
     paged_chunk_prefill_attention_pallas,
     paged_chunk_prefill_attention_quant_pallas, paged_decode_attention_pallas,
@@ -29,6 +31,7 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 # blocks, 16 slots x 64 blocks (2,048 tokens a slot) plus the trash page
 HEADS, HEAD_DIM, BLOCK, SLOTS, SLOT_BLOCKS = 36, 64, 32, 16, 64
 PAGES = SLOTS * SLOT_BLOCKS + 1
+GROUP = paged_pool.head_group(HEAD_DIM, HEADS)
 
 
 @pytest.fixture(scope="module")
@@ -60,13 +63,16 @@ def _compile_for_chip(fn, *specs):
     return text
 
 
-def _pools(dtype, one_chip):
-    shape = (PAGES, BLOCK, HEADS, HEAD_DIM)
+def _pools(dtype, one_chip, layers=2):
+    """Stacked pools in the arena's stored layout (``paged_pool``)."""
+    shape = paged_pool.value_shape(layers, PAGES, BLOCK, HEADS, HEAD_DIM,
+                                   GROUP)
     if dtype == "int8":
+        scales = paged_pool.scale_shape(layers, PAGES, BLOCK, HEADS, GROUP)
         return (_spec(shape, jnp.int8, one_chip),
                 _spec(shape, jnp.int8, one_chip),
-                _spec(shape[:-1], jnp.float32, one_chip),
-                _spec(shape[:-1], jnp.float32, one_chip))
+                _spec(scales, jnp.float32, one_chip),
+                _spec(scales, jnp.float32, one_chip))
     return (_spec(shape, jnp.bfloat16, one_chip),
             _spec(shape, jnp.bfloat16, one_chip))
 
@@ -76,12 +82,11 @@ def test_paged_decode_compiles(one_chip, kv):
     q = _spec((SLOTS, HEADS, HEAD_DIM), jnp.bfloat16, one_chip)
     tables = _spec((SLOTS, SLOT_BLOCKS), jnp.int32, one_chip)
     lens = _spec((SLOTS,), jnp.int32, one_chip)
-    if kv == "int8":
-        _compile_for_chip(paged_decode_attention_quant_pallas, q,
-                          *_pools(kv, one_chip), tables, lens)
-    else:
-        _compile_for_chip(paged_decode_attention_pallas, q,
-                          *_pools(kv, one_chip), tables, lens)
+    layer = _spec((), jnp.int32, one_chip)
+    fn = (paged_decode_attention_quant_pallas if kv == "int8"
+          else paged_decode_attention_pallas)
+    _compile_for_chip(lambda *a: fn(*a[:-1], layer=a[-1], kv_heads=HEADS),
+                      q, *_pools(kv, one_chip), tables, lens, layer)
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -94,9 +99,128 @@ def test_paged_chunk_and_verify_compile(one_chip, kv, rows, T):
     tables = _spec((rows, SLOT_BLOCKS), jnp.int32, one_chip)
     start = _spec((rows,), jnp.int32, one_chip)
     n = _spec((rows,), jnp.int32, one_chip)
+    layer = _spec((), jnp.int32, one_chip)
     fn = (paged_chunk_prefill_attention_quant_pallas if kv == "int8"
           else paged_chunk_prefill_attention_pallas)
-    _compile_for_chip(fn, q, *_pools(kv, one_chip), tables, start, n)
+    _compile_for_chip(lambda *a: fn(*a[:-1], layer=a[-1], kv_heads=HEADS),
+                      q, *_pools(kv, one_chip), tables, start, n, layer)
+
+
+# the whole serving steps over 42 slots of 32 blocks at three attention
+# geometries: minicpm-2b's 36 KV heads of 64 (2 to a row) in the benchmark
+# cell's 1,345 pages; the 9 heads a 4-way model mesh leaves each chip of
+# it (all 9 in a row of 640 lanes, 64 of them padding) in the four times
+# as many pages such a chip holds; and zamba2-7b's 32 heads of 112 (8 to
+# a row of 896).  Each pool is as deep as fits one chip beside the
+# weights, and never small: XLA stages a pool that fits in on-chip memory
+# (128 MiB on a v5e) through relayouts and copies a deployment's pool
+# never gets.
+STEP_GEOMETRIES = {"36x64": (36, 64, 1345, {"int8": 40, "bf16": 16}),
+                   "9x64": (9, 64, 4 * 1344 + 1, {"int8": 40, "bf16": 16}),
+                   "32x112": (32, 112, 1345, {"int8": 32, "bf16": 8})}
+STEP_SLOTS, STEP_BLOCKS = 42, 32
+
+
+def _serving_step_hlo(one_chip, geometry, kv, phase):
+    """Optimized HLO of ``decode_step_paged`` (one token for every slot)
+    or of a 128-token ``prefill_chunk_paged`` over pools as the arena
+    allocates them, donated as the engine donates them."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import transformer
+    from repro.serving.arena import KVArena
+    heads, head_dim, pages, layers = STEP_GEOMETRIES[geometry]
+    cfg = dataclasses.replace(get_config("minicpm-2b"),
+                              num_layers=layers[kv], vocab_size=1024,
+                              num_heads=heads, num_kv_heads=heads,
+                              head_dim=head_dim)
+    arena = KVArena(cfg, transformer.init_cache, capacity=1,
+                    max_seq_len=STEP_BLOCKS * BLOCK, block_size=BLOCK,
+                    kv_dtype=kv)
+    place = lambda tree: jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+    pools = place(arena.pool_structs(pages))
+    params = place(jax.eval_shape(
+        lambda: transformer.init(jax.random.PRNGKey(0), cfg)))
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+
+    def step(params, tokens, pools, lens, live, tables):
+        cache = {"k": pools[0], "v": pools[1], "len": lens}
+        if phase == "decode":
+            logits, new = transformer.decode_step_paged(
+                params, cfg, tokens, cache, tables, live, block_size=BLOCK,
+                impl="pallas")
+        else:
+            logits, new = transformer.prefill_chunk_paged(
+                params, cfg, {"tokens": tokens}, cache, tables,
+                chunk_len=live, block_size=BLOCK, impl="pallas")
+        return logits, [new["k"], new["v"]], new["len"]
+
+    if phase == "decode":
+        args = (i32(STEP_SLOTS), pools, i32(STEP_SLOTS),
+                _spec((STEP_SLOTS,), jnp.bool_, one_chip),
+                i32(STEP_SLOTS, STEP_BLOCKS))
+    else:
+        args = (i32(1, 128), pools, i32(1), i32(), i32(1, STEP_BLOCKS))
+    text = jax.jit(step, donate_argnums=(2,)).lower(
+        params, *args).compile().as_text()
+    return text, pools
+
+
+# an HLO instruction: its name, result shape and opcode
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([0-9,]*)\]\S* "
+                    r"([a-z][a-z0-9-]*)\((.*)$")
+# what may hold a whole pool: the donated buffer, the loop carry, views of
+# it, the in-place row scatter, and the kernel that reads it
+_IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "scatter",
+             "custom-call"}
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("phase", ["decode", "chunk"])
+@pytest.mark.parametrize("geometry", list(STEP_GEOMETRIES))
+def test_serving_step_reads_pools_in_place(one_chip, geometry, kv, phase):
+    """The arena stores each pool in the layout the paged kernels read, so
+    the compiled step moves no whole pool: every instruction shaped like
+    one (by its page or scale-row count) is the donated buffer, the loop
+    carry, a bitcast, the in-place row scatter or the kernel — no copy,
+    transpose, slice or loop fusion — and the kernel's pool operands are
+    the carry or the scatter's result, through bitcasts at most."""
+    text, pools = _serving_step_hlo(one_chip, geometry, kv, phase)
+    pool_dims = {str(a.shape[2]) for pool in pools
+                 for a in jax.tree.leaves(pool)}
+    bodies, name = {}, None       # fused computation -> its instructions
+    for line in text.splitlines():
+        if line.startswith("%"):
+            name = line.split()[0].lstrip("%")
+        bodies[name] = bodies.get(name, "") + line + "\n"
+
+    def scatter_fusion(op, rest):
+        called = re.search(r"calls=%([\w.-]+)", rest)
+        return (op == "fusion" and "kind=kCustom" in rest and called
+                and " scatter(" in bodies.get(called.group(1), ""))
+
+    defs = {}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, _, dims, op, rest = m.groups()
+        defs[name] = (op, rest)
+        if pool_dims & set(dims.split(",")):
+            assert op in _IN_PLACE or scatter_fusion(op, rest), \
+                line.strip()[:300]
+    kernels = [(n, rest) for n, (op, rest) in defs.items()
+               if op == "custom-call" and "tpu_custom_call" in rest]
+    assert len(kernels) == 1
+    operands = re.findall(r"%([\w.-]+)", kernels[0][1].split(")")[0])
+    for name in operands[-len(jax.tree.leaves(pools[0])) * 2:]:
+        while defs[name][0] == "bitcast":
+            name = re.findall(r"%([\w.-]+)", defs[name][1])[0]
+        op, rest = defs[name]
+        assert op in ("parameter", "get-tuple-element") \
+            or scatter_fusion(op, rest), (name, op)
 
 
 def test_flash_prefill_compiles(one_chip):

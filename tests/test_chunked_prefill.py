@@ -193,28 +193,8 @@ def test_chunk_prefill_attention_pallas_matches_ref(rng):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_chunk_prefill_attention_matches_gathered_ref(rng):
-    from repro.kernels import ref
-    from repro.kernels.decode_attention import (
-        paged_chunk_prefill_attention_pallas, paged_gather_ref)
-    B, T, Hq, Hkv, D, bs, nblk, P = 2, 8, 4, 2, 16, 8, 4, 10
-    q = jnp.asarray(rng.normal(size=(B, T, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P + 1, bs, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P + 1, bs, Hkv, D)).astype(np.float32))
-    bt = jnp.asarray(rng.permutation(P)[:B * nblk].reshape(B, nblk)
-                     .astype(np.int32))
-    start = jnp.asarray(np.array([4, 19], np.int32))
-    cl = jnp.asarray(np.array([8, 6], np.int32))
-    want = ref.chunk_attention_ref(q, paged_gather_ref(kp, bt),
-                                   paged_gather_ref(vp, bt), start, cl)
-    got = paged_chunk_prefill_attention_pallas(q, kp, vp, bt, start, cl,
-                                               interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
 def test_ops_chunk_attention_dispatch(rng):
-    from repro.kernels import ops
+    from repro.kernels import ops, paged_pool
     B, S, T, Hq, Hkv, D = 1, 16, 4, 2, 2, 8
     q = jnp.asarray(rng.normal(size=(B, T, Hq, D)).astype(np.float32))
     kc = jnp.asarray(rng.normal(size=(B, S, Hkv, D)).astype(np.float32))
@@ -222,11 +202,14 @@ def test_ops_chunk_attention_dispatch(rng):
     out = ops.chunk_attention(q, kc, vc, 2, 4, impl="ref")
     assert out.shape == (B, T, Hq, D)
     assert np.isfinite(np.asarray(out)).all()
-    kp = jnp.asarray(rng.normal(size=(5, 8, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(5, 8, Hkv, D)).astype(np.float32))
+    kp = paged_pool.from_natural(jnp.asarray(
+        rng.normal(size=(1, 5, 8, Hkv, D)).astype(np.float32)))
+    vp = paged_pool.from_natural(jnp.asarray(
+        rng.normal(size=(1, 5, 8, Hkv, D)).astype(np.float32)))
     bt = jnp.asarray(np.array([[0, 1]], np.int32))
     out = ops.paged_chunk_attention(q, kp, vp, bt, jnp.asarray([2]),
-                                    jnp.asarray([4]), impl="ref")
+                                    jnp.asarray([4]), kv_heads=Hkv,
+                                    impl="ref")
     assert out.shape == (B, T, Hq, D)
 
 

@@ -12,6 +12,7 @@ from hypothesis import strategies as st
 
 from repro.core.allocator import DPGroupRouter, ParallelPlan
 from repro.core.categories import Sensitivity, TaskCategory
+from repro.kernels import paged_pool
 from repro.models import transformer as T
 from repro.serving.arena import KVArena
 from repro.serving.batching import BSComposer, Composer, MFComposer
@@ -39,8 +40,12 @@ def test_arena_classifies_leaves_and_sizes_pool(dense_cfg):
     assert a.pool_blocks == 15 and a.trash_block == 15
     assert len(a.pages) == 2          # k and v are paged
     assert len(a.state) == 0          # dense cfg has no fixed state leaves
-    assert a.pages[0].shape == (dense_cfg.num_layers, 16, 8,
-                                dense_cfg.num_kv_heads, dense_cfg.head_dim)
+    # stored as the paged kernels read it: (layers, Hkv/G, pages, block,
+    # W); the toy's two 16-lane KV heads share one row of 128 lanes, 96 of
+    # them padding
+    Hkv, D = dense_cfg.num_kv_heads, dense_cfg.head_dim
+    assert (Hkv, D) == (2, 16) and paged_pool.head_group(D, Hkv) == 2
+    assert a.pages[0].shape == (dense_cfg.num_layers, 1, 16, 8, 128)
     assert a.token_bytes > 0
 
 
@@ -310,36 +315,22 @@ def test_moe_decode_rows_are_batch_independent():
 
 
 # ---------------------------------------------------------------------------
-# paged decode kernel: Pallas (interpret) vs dense-gather ref
+# paged decode: ref dispatch (the kernel's parity with the oracle is
+# tests/test_paged_native.py::test_paged_kernels_read_stored_layout)
 # ---------------------------------------------------------------------------
-
-def test_paged_decode_attention_matches_gathered_ref(rng):
-    from repro.kernels.decode_attention import (paged_decode_attention_pallas,
-                                                paged_gather_ref)
-    from repro.kernels.ref import decode_attention_ref
-    B, Hq, Hkv, D, bs, nblk, P = 3, 4, 2, 16, 16, 3, 10
-    q = jnp.asarray(rng.normal(size=(B, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P + 1, bs, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P + 1, bs, Hkv, D)).astype(np.float32))
-    bt = jnp.asarray(rng.permutation(P)[:B * nblk]
-                     .reshape(B, nblk).astype(np.int32))
-    lens = jnp.asarray(np.array([5, 33, 48], np.int32))
-    want = decode_attention_ref(q, paged_gather_ref(kp, bt),
-                                paged_gather_ref(vp, bt), lens)
-    got = paged_decode_attention_pallas(q, kp, vp, bt, lens, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
 
 def test_ops_paged_decode_attention_ref_dispatch(rng):
     from repro.kernels import ops
     B, Hq, Hkv, D, bs, nblk, P = 2, 2, 2, 8, 8, 2, 6
     q = jnp.asarray(rng.normal(size=(B, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P + 1, bs, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P + 1, bs, Hkv, D)).astype(np.float32))
+    kp = paged_pool.from_natural(jnp.asarray(
+        rng.normal(size=(1, P + 1, bs, Hkv, D)).astype(np.float32)))
+    vp = paged_pool.from_natural(jnp.asarray(
+        rng.normal(size=(1, P + 1, bs, Hkv, D)).astype(np.float32)))
     bt = jnp.asarray(np.array([[0, 1], [2, 3]], np.int32))
     lens = jnp.asarray(np.array([7, 12], np.int32))
-    out = ops.paged_decode_attention(q, kp, vp, bt, lens, impl="ref")
+    out = ops.paged_decode_attention(q, kp, vp, bt, lens, kv_heads=Hkv,
+                                     impl="ref")
     assert out.shape == (B, Hq, D)
     assert np.isfinite(np.asarray(out)).all()
 
@@ -479,6 +470,55 @@ def test_arena_capacity_fits_device_memory(dense_cfg):
     done = rt.drain()
     assert len(done) == cap + 2 and rt.groups[0].arena.capacity == cap
     assert rt.total_slots() == cap
+
+
+@pytest.mark.parametrize("path", ["speculate", "oneshot"])
+def test_arena_capacity_counts_every_program(dense_cfg, path):
+    """Besides the fused decode and chunk steps the fit counts what a
+    path keeps loaded or runs: the draft's own arena and its decode step
+    and the verify step when speculating, the one-shot prefill of a
+    longest prompt when prompts are not chunked.  Where the budget fits
+    8 plain slots, the path gets fewer, and those leave room for its
+    own program's code and temporaries."""
+    params = T.init(jax.random.PRNGKey(0), dense_cfg)
+    kw = (dict(draft_params=params, draft_cfg=dense_cfg, speculate=2)
+          if path == "speculate" else dict(chunked_prefill=False))
+
+    def runtime(free_bytes):
+        rt = ServiceRuntime(dense_cfg, params, _plan(bs=64), max_seq_len=40,
+                            block_size=8, **kw)
+        rt._free_device_bytes = lambda: free_bytes
+        return rt
+
+    plain = _fitted_runtime(dense_cfg, free_bytes=0)
+    probe = plain._new_arena(1)
+    budget = plain._step_scratch_bytes(probe, 8) \
+        + 8 * probe.device_slot_bytes()
+    rt = runtime(budget)
+    per_slot = probe.device_slot_bytes()
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if path == "speculate":
+        per_slot += rt._new_draft_arena().device_slot_bytes()
+        cap = rt._fit_capacity()
+        u32 = jax.ShapeDtypeStruct((cap,), jnp.uint32)
+        flags = jax.ShapeDtypeStruct((cap,), bool)
+        arena = rt._new_arena(cap)
+        own = rt._build_verify_fn(arena).lower(
+            params, i32(cap, 3), jax.ShapeDtypeStruct(
+                (cap, 2, dense_cfg.vocab_size), jnp.float32), i32(cap, 2),
+            arena.pages, arena.state, arena.lens, flags, u32, u32, u32,
+            i32(cap, arena.blocks_per_slot), flags)
+    else:
+        cap = rt._fit_capacity()
+        own = rt.prefill_fn.lower(params, {"tokens": i32(1, 39)}, 40)
+    mem = own.compile().memory_analysis()
+    need = (mem.generated_code_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    scratch = rt._step_scratch_bytes(probe, cap)
+    assert 1 <= cap < 8
+    assert scratch > plain._step_scratch_bytes(probe, cap)
+    assert scratch >= need
+    assert cap * per_slot + scratch <= budget
 
 
 def test_arena_capacity_fails_loudly_without_one_slot(dense_cfg):

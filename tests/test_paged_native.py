@@ -20,6 +20,7 @@ from hypothesis import strategies as st
 
 from repro.core.allocator import ParallelPlan
 from repro.core.categories import Sensitivity, TaskCategory
+from repro.kernels import paged_pool
 from repro.models.registry import model_api
 from repro.serving.engine import GenerationRequest, ServiceRuntime
 
@@ -241,41 +242,114 @@ def test_decode_cost_analysis_keeps_compile_counters(dense_cfg):
 # kernels: ref fallback's length-clipped gather stays bit-identical
 # ---------------------------------------------------------------------------
 
+def _stored(rng, P, bs, Hkv, D):
+    """A random natural (P, bs, Hkv, D) pool and the same pool as one
+    layer of the arena's stored layout."""
+    nat = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)).astype(np.float32))
+    return nat, paged_pool.from_natural(nat[None])
+
+
 def test_paged_decode_ref_masked_gather_bit_identical(rng):
     """ops.paged_decode_attention's ref fallback clips the block table to
     per-slot up-to-len rows (past-len entries read the one trash page).
     The clip must be invisible to the math: bit-identical to the oracle
     on the UNCLIPPED gather."""
     from repro.kernels import ops, ref
-    from repro.kernels.decode_attention import paged_gather_ref
     B, Hq, Hkv, D, bs, nblk, P = 3, 4, 2, 16, 8, 4, 14
     q = jnp.asarray(rng.normal(size=(B, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)).astype(np.float32))
+    kn, kp = _stored(rng, P, bs, Hkv, D)
+    vn, vp = _stored(rng, P, bs, Hkv, D)
     bt = jnp.asarray(rng.permutation(P - 1)[:B * nblk]
                      .reshape(B, nblk).astype(np.int32))
     lens = jnp.asarray(np.array([3, 17, 32], np.int32))
-    want = ref.decode_attention_ref(q, paged_gather_ref(kp, bt),
-                                    paged_gather_ref(vp, bt), lens)
-    got = ops.paged_decode_attention(q, kp, vp, bt, lens, impl="ref")
+    gather = lambda p: p[bt].reshape(B, nblk * bs, Hkv, D)
+    want = ref.decode_attention_ref(q, gather(kn), gather(vn), lens)
+    got = ops.paged_decode_attention(q, kp, vp, bt, lens, kv_heads=Hkv,
+                                     impl="ref")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_paged_chunk_ref_masked_gather_bit_identical(rng):
     from repro.kernels import ops, ref
-    from repro.kernels.decode_attention import paged_gather_ref
     B, T, Hq, Hkv, D, bs, nblk, P = 2, 8, 4, 2, 16, 8, 4, 10
     q = jnp.asarray(rng.normal(size=(B, T, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)).astype(np.float32))
+    kn, kp = _stored(rng, P, bs, Hkv, D)
+    vn, vp = _stored(rng, P, bs, Hkv, D)
     bt = jnp.asarray(rng.permutation(P - 1)[:B * nblk]
                      .reshape(B, nblk).astype(np.int32))
     start = jnp.asarray(np.array([4, 19], np.int32))
     cl = jnp.asarray(np.array([8, 6], np.int32))
-    want = ref.chunk_attention_ref(q, paged_gather_ref(kp, bt),
-                                   paged_gather_ref(vp, bt), start, cl)
-    got = ops.paged_chunk_attention(q, kp, vp, bt, start, cl, impl="ref")
+    gather = lambda p: p[bt].reshape(B, nblk * bs, Hkv, D)
+    want = ref.chunk_attention_ref(q, gather(kn), gather(vn), start, cl)
+    got = ops.paged_chunk_attention(q, kp, vp, bt, start, cl, kv_heads=Hkv,
+                                    impl="ref")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# (Hq, Hkv, D), the KV heads the stored layout packs into a row, and the
+# kernel's tile budget (query rows x lanes; None keeps the default)
+_GEOMETRIES = {
+    "D64-G2": ((4, 4, 64), 2, None),     # minicpm-2b's 64-lane heads
+    "D128-G1": ((4, 2, 128), 1, None),   # heads as wide as a row
+    "D64-GQA": ((8, 2, 64), 2, None),    # 4 query heads per KV head
+    "D64-odd": ((3, 3, 64), 3, None),    # 3 heads in a row of 256 lanes
+    "D112-G8": ((8, 8, 112), 8, None),   # zamba2-7b's heads, 896 lanes
+    "D112-pad": ((4, 2, 112), 1, None),  # one head, 16 padding lanes
+    "D64-GQA-split": ((8, 2, 64), 2, 1024),  # a group's heads in 8 tiles
+    "D16-GQA": ((4, 2, 16), 2, None),    # 2 narrow heads, 96 padding lanes
+}
+
+
+@pytest.mark.parametrize("geometry", list(_GEOMETRIES))
+@pytest.mark.parametrize("kv", ["float", "int8"])
+@pytest.mark.parametrize("phase", ["decode", "chunk"])
+def test_paged_kernels_read_stored_layout(phase, kv, geometry, monkeypatch):
+    """The paged kernels (interpret mode) read the arena's stored layout
+    — head groups packed into lane rows (padding lanes where a row is not
+    whole tiles), int8 scales in folded rows, one layer of a stacked pool
+    through its layer index, a group's query heads in one tile or split
+    over several — and agree with the ref path on the same pools and
+    with the oracle on the natural-layout gather of the same
+    (dequantized) values."""
+    from repro.kernels import decode_attention, ops, ref
+    from repro.kernels.quant import dequantize, quantize
+    (Hq, Hkv, D), G, tile = _GEOMETRIES[geometry]
+    if tile is not None:
+        monkeypatch.setattr(decode_attention, "_TILE_ELEMS", tile)
+    assert paged_pool.head_group(D, Hkv) == G
+    B, bs, nblk, L, layer = 3, 8, 4, 2, 1
+    P = B * nblk + 1
+    rng = np.random.default_rng(sorted(_GEOMETRIES).index(geometry))
+    nat = [jnp.asarray(rng.normal(size=(L, P, bs, Hkv, D)), jnp.float32)
+           for _ in range(2)]
+    quant = kv == "int8"
+    kp, vp = (paged_pool.from_natural(n, quantized=quant) for n in nat)
+    W = paged_pool.row_lanes(G, D)
+    assert W % 128 == 0 and W - G * D < 128
+    assert paged_pool.values_of(kp).shape == (L, Hkv // G, P, bs, W)
+    if quant:
+        nat = [dequantize(*quantize(n)) for n in nat]
+    bt = jnp.asarray(rng.permutation(P - 1).reshape(B, nblk)
+                     .astype(np.int32))
+    gather = lambda n: n[layer][bt].reshape(B, nblk * bs, Hkv, D)
+    if phase == "decode":
+        q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
+        lens = jnp.asarray([1, 17, nblk * bs], jnp.int32)
+        want = ref.decode_attention_ref(q, *map(gather, nat), lens)
+        run = lambda impl: ops.paged_decode_attention(
+            q, kp, vp, bt, lens, layer=layer, kv_heads=Hkv, impl=impl)
+    else:
+        q = jnp.asarray(rng.normal(size=(B, 5, Hq, D)), jnp.float32)
+        start = jnp.asarray([0, 9, nblk * bs - 5], jnp.int32)
+        cl = jnp.asarray([5, 3, 5], jnp.int32)
+        want = ref.chunk_attention_ref(q, *map(gather, nat), start, cl)
+        run = lambda impl: ops.paged_chunk_attention(
+            q, kp, vp, bt, start, cl, layer=layer, kv_heads=Hkv,
+            impl=impl)
+    np.testing.assert_allclose(np.asarray(run("ref")), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(run("pallas_interpret")),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 def test_paged_native_pallas_interpret_matches_ref():

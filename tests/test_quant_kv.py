@@ -23,7 +23,7 @@ from hypothesis import strategies as st
 from repro.core.allocator import ParallelPlan
 from repro.core.categories import (KV_DTYPE_BY_SENSITIVITY, Sensitivity,
                                    TaskCategory)
-from repro.kernels import ops
+from repro.kernels import ops, paged_pool
 from repro.kernels.quant import QuantPages, dequantize, quantize
 from repro.models.registry import model_api
 from repro.serving.arena import KVArena
@@ -131,13 +131,17 @@ def test_quant_pages_is_a_transparent_pytree():
 # ---------------------------------------------------------------------------
 
 def _paged_fixture(seed, B=2, blocks=4, bs=8, Hq=4, Hkv=2, D=16):
+    """Float pools in the arena's stored layout (one layer), the same
+    pools as int8 ``QuantPages``, a block table and lengths."""
     rng = np.random.default_rng(seed)
     P = B * blocks + 1                                    # + trash page
-    kp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)), jnp.float32)
+    nat = [jnp.asarray(rng.normal(size=(1, P, bs, Hkv, D)), jnp.float32)
+           for _ in range(2)]
+    kp, vp = (paged_pool.from_natural(n) for n in nat)
+    kq, vq = (paged_pool.from_natural(n, quantized=True) for n in nat)
     bt = jnp.arange(B * blocks, dtype=jnp.int32).reshape(B, blocks)
     lens = jnp.asarray(rng.integers(1, blocks * bs + 1, B), jnp.int32)
-    return kp, vp, bt, lens, (Hq, D)
+    return (kp, vp), (kq, vq), bt, lens, (Hq, Hkv, D)
 
 
 @settings(max_examples=_EXAMPLES, deadline=None, derandomize=True)
@@ -146,12 +150,12 @@ def test_quant_paged_decode_interpret_matches_ref(seed):
     """The fused dequant decode kernel (interpret mode) must reproduce the
     ref path's gather→dequant→oracle to float fuzz: both consume the SAME
     int8 values + f32 scales, so any gap is kernel logic, not rounding."""
-    kp, vp, bt, lens, (Hq, D) = _paged_fixture(seed)
-    kq, vq = QuantPages(*quantize(kp)), QuantPages(*quantize(vp))
+    _, (kq, vq), bt, lens, (Hq, Hkv, D) = _paged_fixture(seed)
     q = jnp.asarray(np.random.default_rng(seed + 1).normal(
         size=(bt.shape[0], Hq, D)), jnp.float32)
-    out_ref = ops.paged_decode_attention(q, kq, vq, bt, lens, impl="ref")
-    out_pl = ops.paged_decode_attention(q, kq, vq, bt, lens,
+    out_ref = ops.paged_decode_attention(q, kq, vq, bt, lens, kv_heads=Hkv,
+                                         impl="ref")
+    out_pl = ops.paged_decode_attention(q, kq, vq, bt, lens, kv_heads=Hkv,
                                         impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(out_pl), np.asarray(out_ref),
                                atol=1e-5, rtol=1e-5)
@@ -162,18 +166,18 @@ def test_quant_paged_decode_interpret_matches_ref(seed):
 def test_quant_paged_chunk_interpret_matches_ref(seed, chunk):
     """Quantized chunked-prefill: same exact-parity contract as decode,
     with per-slot start offsets and causal masking inside the chunk."""
-    kp, vp, bt, lens, (Hq, D) = _paged_fixture(seed)
-    kq, vq = QuantPages(*quantize(kp)), QuantPages(*quantize(vp))
+    _, (kq, vq), bt, lens, (Hq, Hkv, D) = _paged_fixture(seed)
     B = bt.shape[0]
     rng = np.random.default_rng(seed + 2)
+    bs = paged_pool.block_size_of(kq)
     start = jnp.asarray([int(l) for l in np.minimum(
-        np.asarray(lens), bt.shape[1] * kp.shape[1] - chunk)], jnp.int32)
+        np.asarray(lens), bt.shape[1] * bs - chunk)], jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, chunk, Hq, D)), jnp.float32)
     cl = jnp.full((B,), chunk, jnp.int32)
     out_ref = ops.paged_chunk_attention(q, kq, vq, bt, start, cl,
-                                        impl="ref")
+                                        kv_heads=Hkv, impl="ref")
     out_pl = ops.paged_chunk_attention(q, kq, vq, bt, start, cl,
-                                       impl="pallas_interpret")
+                                       kv_heads=Hkv, impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(out_pl), np.asarray(out_ref),
                                atol=1e-5, rtol=1e-5)
 
@@ -184,12 +188,13 @@ def test_quantized_decode_tracks_unquantized_oracle(seed):
     """int8 pools vs the same pools unquantized: attention output drifts
     only by the quantization noise (unit-normal K/V → well under 5e-2),
     never structurally (wrong rows / dropped blocks would blow this up)."""
-    kp, vp, bt, lens, (Hq, D) = _paged_fixture(seed)
-    kq, vq = QuantPages(*quantize(kp)), QuantPages(*quantize(vp))
+    (kp, vp), (kq, vq), bt, lens, (Hq, Hkv, D) = _paged_fixture(seed)
     q = jnp.asarray(np.random.default_rng(seed + 3).normal(
         size=(bt.shape[0], Hq, D)), jnp.float32)
-    exact = ops.paged_decode_attention(q, kp, vp, bt, lens, impl="ref")
-    approx = ops.paged_decode_attention(q, kq, vq, bt, lens, impl="ref")
+    exact = ops.paged_decode_attention(q, kp, vp, bt, lens, kv_heads=Hkv,
+                                       impl="ref")
+    approx = ops.paged_decode_attention(q, kq, vq, bt, lens, kv_heads=Hkv,
+                                        impl="ref")
     np.testing.assert_allclose(np.asarray(approx), np.asarray(exact),
                                atol=5e-2)
 
