@@ -49,6 +49,9 @@ class Driver:
         self.profile_window: Optional[tuple] = None
         self.in_window = False
         self.queue = (0, 0)                 # waiting requests: window start, end
+        self.waits: List[tuple] = []        # (t, requests, prompt tokens)
+        #                                     waiting for a first token,
+        #                                     after each round of the window
         self.settled = 0.0                  # when ``settle`` stopped
         self.gc_pauses: List[tuple] = []    # (start, seconds, generation)
         self._gc_t0 = 0.0
@@ -78,6 +81,16 @@ class Driver:
         for r in new:
             self.results.append((t1, r))
         return new
+
+    def waiting(self):
+        """Requests that have not had their first token (queued for a slot,
+        or prefilling in one), and the prompt tokens the prefilling ones
+        have left."""
+        rt = self.stack.runtime
+        slots = [s for g in rt.groups.values() for s in g.slots
+                 if s.prefilling]
+        return (rt.pending() + len(slots),
+                sum(len(s.req.tokens) - s.consumed for s in slots))
 
     def busy(self) -> bool:
         rt = self.stack.runtime
@@ -164,8 +177,15 @@ class Driver:
         while time.perf_counter() < warm_end:
             self._open_round(loop.phases["warmup"], state, t_warm, warm_end)
         state = [0]
+        reqs = loop.phases["window"]
         self._window(seconds, lambda t_end: self._open_round(
-            loop.phases["window"], state, self.window[0], t_end), profile)
+            reqs, state, self.window[0], t_end), profile)
+        # an arrival due in the window's last round is the window's all the
+        # same: it is sent now, and its first token counts from when it was
+        # due, so every run holds the same requests whatever its timing
+        for r in reqs[state[0]:]:
+            self.submit(self.rid_base + r.idx, r.stream, self._tokens(r),
+                        r.max_new, self.window[0] + r.arrival_s)
 
     def settle(self, first_token_seen) -> None:
         """After the window, with nothing more sent: step until every
@@ -219,6 +239,7 @@ class Driver:
                 started = True
                 self.profile_window = (time.perf_counter(), None)
             one_round(t_end)
+            self.waits.append((time.perf_counter(), *self.waiting()))
         self.in_window = False
         self.queue = (q0, self.stack.runtime.pending())
         if started:

@@ -13,11 +13,10 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from types import ModuleType
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
-
-from .counts import Dims
 
 _COMPLETE, _INSTANT = "X", "i"
 
@@ -41,7 +40,8 @@ def percentile(xs, q: float) -> Optional[float]:
 class RunRecord:
     cell: str
     chips: int
-    dims: Dims
+    family: ModuleType                  # bench/families/<family>.py
+    dims: Any                           # the family's record of sizes
     peaks: Dict
     slots: int
     window: Tuple[float, float]

@@ -1,20 +1,14 @@
-"""The plain reference forward and the lower-precision control.
+"""The served-token check against the plain reference, and the pieces
+every family's reference forward shares.
 
-Written from the layer equations alone; it imports nothing of the program
-and takes only the weights the benchmark drew.  Dense decoder, as the
-program's dense family computes it:
-
-    x = E[tokens]
-    per layer:  h = rms(x) * g1;  q, k, v = h Wq + bq, h Wk + bk, h Wv + bv
-                q, k = rope(q), rope(k)    (half-split rotation, theta)
-                x = x + softmax(q k^T / sqrt(D), causal) v Wo
-                h = rms(x) * g2;  x = x + (silu(h Wg) * (h Wu)) Wd
-    logits = (rms(x) * gf) E^T   (tied)   or   (rms(x) * gf) U
-
-All in float32 at ``highest`` matmul precision.  The control computes
-every matrix product of the same forward with both operands rounded to
-float8 (e4m3, scaled per row of the activations and per output column of
-the weights): the step below bf16 that a later change might take.
+A family's ``logits(params, dims, tokens, *, low=False)``
+(``bench/families/<family>.py``) is written from its layer equations
+alone; it imports nothing of the program and takes only the weights the
+benchmark drew.  All in float32 at ``highest`` matmul precision.  Its
+control (``low``) computes every matrix product of the same forward with
+both operands rounded to float8 (e4m3, scaled per row of the activations
+and per output column of the weights, ``_mm``): the step below bf16 that
+a later change might take.
 """
 from __future__ import annotations
 
@@ -23,8 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .counts import Dims
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
@@ -58,56 +50,17 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def logits(params, dims: Dims, tokens, *, eps: float, theta: float,
-           low: bool = False):
-    """(L, V) float32 logits of one token sequence."""
-    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
-    L = tokens.shape[0]
-    H, Hk, D = dims.heads, dims.kv_heads, dims.head_dim
-    x = params["embed"]["embedding"][tokens].astype(jnp.float32)
-    causal = jnp.tril(jnp.ones((L, L), bool))
-
-    def layer(x, lp):
-        lp = f32(lp)
-        at = lp["attn"]
-        h = _rms(x, lp["ln1"]["w"], eps)
-        q = _mm(h, at["wq"], low) + at.get("bq", 0.0)
-        k = _mm(h, at["wk"], low) + at.get("bk", 0.0)
-        v = _mm(h, at["wv"], low) + at.get("bv", 0.0)
-        q = _rope(q.reshape(L, H, D), theta)
-        k = _rope(k.reshape(L, Hk, D), theta)
-        v = v.reshape(L, Hk, D)
-        k, v = jnp.repeat(k, H // Hk, 1), jnp.repeat(v, H // Hk, 1)
-        s = jnp.einsum("qhd,khd->hqk", q, k, precision=HI) * D ** -0.5
-        p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", p, v, precision=HI).reshape(L, H * D)
-        x = x + _mm(o, at["wo"], low)
-        h = _rms(x, lp["ln2"]["w"], eps)
-        m = lp["mlp"]
-        g = jax.nn.silu(_mm(h, m["w_gate"], low)) * _mm(h, m["w_up"], low)
-        return x + _mm(g, m["w_down"], low), None
-
-    x, _ = jax.lax.scan(layer, x, params["blocks"])
-    x = _rms(x, params["ln_f"]["w"].astype(jnp.float32), eps)
-    if dims.tied:
-        head = params["embed"]["embedding"].astype(jnp.float32).T
-    else:
-        head = params["embed"]["unembed"].astype(jnp.float32)
-    return _mm(x, head, low)
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "eps", "theta",
-                                             "control"))
-def _gaps(params, seq, n_prompt, served, *, dims, eps, theta, control):
+@functools.partial(jax.jit, static_argnames=("logits", "dims", "control"))
+def _gaps(params, seq, n_prompt, served, *, logits, dims, control):
     """Per served position j (row ``n_prompt - 1 + j`` predicts served
     token j): how far below the reference's best logit the judged token
     lies, in units of that row's reference-logit standard deviation.  The
     judged token is the served one, or under ``control`` the token the
     fp8 forward puts first."""
-    ref = logits(params, dims, seq, eps=eps, theta=theta)
+    ref = logits(params, dims, seq)
     rows = jax.lax.dynamic_slice_in_dim(ref, n_prompt - 1, served.shape[0])
     if control:
-        low = logits(params, dims, seq, eps=eps, theta=theta, low=True)
+        low = logits(params, dims, seq, low=True)
         lrows = jax.lax.dynamic_slice_in_dim(low, n_prompt - 1,
                                              served.shape[0])
         judged = jnp.argmax(lrows, axis=1)
@@ -117,9 +70,10 @@ def _gaps(params, seq, n_prompt, served, *, dims, eps, theta, control):
     return (rows.max(axis=1) - got) / rows.std(axis=1)
 
 
-def served_gaps(params, dims: Dims, samples, *, width: int, served_max: int,
-                eps: float, theta: float, control: bool = False):
-    """``samples``: (prompt, served tokens) pairs.  Each is run once,
+def served_gaps(params, logits, dims, samples, *, width: int,
+                served_max: int, control: bool = False):
+    """``logits``: the family's reference forward, ``dims`` its sizes;
+    ``samples``: (prompt, served tokens) pairs.  Each is run once,
     teacher-forced, padded to ``width`` so one program serves all (causal:
     the padding never reaches the rows read).  Returns one array of gaps
     per sample."""
@@ -132,7 +86,7 @@ def served_gaps(params, dims: Dims, samples, *, width: int, served_max: int,
             pad = np.zeros((served_max,), np.int32)
             pad[:len(served)] = served
             g = _gaps(params, jnp.asarray(seq), jnp.int32(len(prompt)),
-                      jnp.asarray(pad), dims=dims, eps=eps, theta=theta,
+                      jnp.asarray(pad), logits=logits, dims=dims,
                       control=control)
             out.append(np.asarray(g)[:len(served)])
     return out

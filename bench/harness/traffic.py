@@ -10,6 +10,10 @@ distributions:
 
     {"loop": "open", "rate_per_s": 2.0, "warmup_s": 10, ...}
 
+A mix may also set the deployment it runs against: ``max_seq_len``
+(tokens of a slot, for its longest request) and ``prefill_chunk`` (the
+plan's prompt tokens a round); ``cells.Cell`` reads both.
+
 Lengths are lognormal (``median``, log-space ``sigma``), clipped to
 ``[min, max]``.  Lengths and inter-arrival gaps are drawn at evenly
 spaced quantiles into one fixed schedule per mix (see each loop), which

@@ -1,11 +1,11 @@
 """Random weights from the seed, made by the benchmark itself.
 
 The benchmark, not the program, draws the weights: the reference then
-shares nothing the program made.  They are drawn on the device in one
-jitted call, in bf16 (the type they are served in), and laid out as the
-dense family's parameter tree expects (layers stacked on a leading axis).
-Under a mesh they are born with the shardings the program's serving
-placement gives them, so no device holds the whole model.
+shares nothing the program made.  A family's ``layout(dims)`` gives every
+leaf as ``(shape, kind)``, nested like the program's parameter tree; they
+are drawn on the device in one jitted call, in bf16 (the type they are
+served in).  Under a mesh they are born with the shardings the program's
+serving placement gives them, so no device holds the whole model.
 
 Matrices and the embedding are N(0, 0.02^2), the initializer range of
 the Llama and Qwen families: with it every layer adds to the residual
@@ -23,44 +23,16 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .counts import Dims
-
 INIT_STD = 0.02
-
-
-def layout(dims: Dims) -> Dict[str, Any]:
-    """``{path: (shape, kind)}`` nested like the parameter tree."""
-    L, d, f = dims.layers, dims.d_model, dims.d_ff
-    a, kv = dims.heads * dims.head_dim, dims.kv_heads * dims.head_dim
-    attn = {"wq": ((L, d, a), "matrix"), "wk": ((L, d, kv), "matrix"),
-            "wv": ((L, d, kv), "matrix"), "wo": ((L, a, d), "matrix")}
-    if dims.qkv_bias:
-        attn.update(bq=((L, a), "bias"), bk=((L, kv), "bias"),
-                    bv=((L, kv), "bias"))
-    embed = {"embedding": ((dims.vocab, d), "embed")}
-    if not dims.tied:
-        embed["unembed"] = ((d, dims.vocab), "matrix")
-    return {
-        "embed": embed,
-        "blocks": {
-            "ln1": {"w": ((L, d), "norm")},
-            "attn": attn,
-            "ln2": {"w": ((L, d), "norm")},
-            "mlp": {"w_gate": ((L, d, f), "matrix"),
-                    "w_up": ((L, d, f), "matrix"),
-                    "w_down": ((L, f, d), "matrix")},
-        },
-        "ln_f": {"w": ((d,), "norm")},
-    }
 
 
 def _is_spec(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
 
 
-def shapes(dims: Dims):
+def shapes(layout: Dict[str, Any]):
     return jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s[0], jnp.bfloat16), layout(dims),
+        lambda s: jax.ShapeDtypeStruct(s[0], jnp.bfloat16), layout,
         is_leaf=_is_spec)
 
 
@@ -77,11 +49,11 @@ def _draw(key, spec: Tuple) -> jax.Array:
     return z.astype(jnp.bfloat16)
 
 
-def init_weights(dims: Dims, seed: int, shardings=None):
-    """The weights for ``seed``, made on the device in one jitted call;
-    ``shardings`` (a tree like ``layout``) places them on a mesh."""
-    tree = layout(dims)
-    leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_spec)
+def init_weights(layout: Dict[str, Any], seed: int, shardings=None):
+    """The weights of ``layout`` for ``seed``, made on the device in one
+    jitted call; ``shardings`` (a tree like ``layout``) places them on a
+    mesh."""
+    leaves, treedef = jax.tree.flatten(layout, is_leaf=_is_spec)
 
     def make(key):
         return jax.tree.unflatten(treedef, [

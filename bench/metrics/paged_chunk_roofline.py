@@ -2,7 +2,7 @@
 attention kernel): operations and bytes the algorithm needs per chunk
 call, from each chunk's start and length, over the kernel's device time
 per call, in percent."""
-from harness import counts, layers
+from harness import layers
 
 
 def read(rec):
@@ -14,7 +14,7 @@ def read(rec):
                                  layers.CHUNK_KERNEL)
     if not calls or t is None:
         return None
-    work = [counts.paged_chunk_kernel(rec.dims, s, n) for s, n in calls]
+    work = [rec.family.paged_chunk_kernel(rec.dims, s, n) for s, n in calls]
     ops = sum(w[0] for w in work) / len(work)
     byt = sum(w[1] for w in work) / len(work)
     return layers.share(rec, ops, byt, t)

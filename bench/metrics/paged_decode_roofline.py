@@ -3,7 +3,7 @@ kernel, int8 or bf16 as the cell runs): operations and bytes the algorithm
 needs per decode step, from the live requests' cache lengths, over the
 kernel's device time per step, in percent.  Under ``shard_map`` each
 device runs its share of the heads."""
-from harness import counts, layers
+from harness import layers
 
 
 def read(rec):
@@ -15,7 +15,7 @@ def read(rec):
                                  layers.DECODE_KERNEL)
     if not steps or t is None:
         return None
-    work = [counts.paged_decode_kernel(rec.dims, s) for s in steps]
+    work = [rec.family.paged_decode_kernel(rec.dims, s) for s in steps]
     ops = sum(w[0] for w in work) / len(work)
     byt = sum(w[1] for w in work) / len(work)
     return layers.share(rec, ops, byt, t)

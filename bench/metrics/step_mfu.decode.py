@@ -4,7 +4,7 @@ the cache read, new rows written) could take at the chip's peaks, over the
 step's device time, in percent.  Source: the profiled window's executions
 of the decode program, and the live requests' cache lengths at each
 decode round from the program's tracer."""
-from harness import counts, layers
+from harness import layers
 
 
 def read(rec):
@@ -15,7 +15,7 @@ def read(rec):
     t = layers.mean_time_per_run(rec, layers.DECODE_PROGRAM)
     if not steps or t is None:
         return None
-    work = [counts.decode_step(rec.dims, s) for s in steps]
+    work = [rec.family.decode_step(rec.dims, s) for s in steps]
     ops = sum(w[0] for w in work) / len(work)
     byt = sum(w[1] for w in work) / len(work)
     return layers.share(rec, ops, byt, t)

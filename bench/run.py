@@ -7,8 +7,8 @@
 A cell (``workloads`` in ``BENCHMARK.json``) is one model configuration
 under one traffic mix.  A run builds the serving stack as the launcher
 does (one server, the EPARA plan, one ``ServiceRuntime`` with the
-category's default knobs, paged Pallas kernels; a ``(1, chips)`` mesh for
-a four-chip cell), with weights the benchmark draws from ``--seed`` on the
+category's default knobs but the slot length and prefill chunk a mix
+sets, paged Pallas kernels; a ``(1, chips)`` mesh for a four-chip cell), with weights the benchmark draws from ``--seed`` on the
 device.  It warms every shape the traffic reaches (set-up), then drives
 ``ClusterSupervisor.submit`` / ``step`` from its own clock for
 ``--seconds``.  The seed draws the weights and every prompt's token ids;
@@ -27,22 +27,32 @@ exits 2.
 
 Layout (everything found by the names in ``BENCHMARK.json``):
 
-    bench/configs/<config>.json   a configuration: arch id, published
-                                  sizes as run, max_seq_len, check limit
+    bench/configs/<config>.json   a configuration: arch id, model family,
+                                  published sizes as run, max_seq_len,
+                                  check limit
+    bench/families/<family>.py    a model family: the program's config
+                                  fields, sizes, weight layout, plain
+                                  reference forward and its control, the
+                                  operation and byte counts
     bench/traffic/<traffic>.json  a traffic mix: loop, rate or streams,
-                                  length distributions
+                                  length distributions; max_seq_len where
+                                  its longest request needs another, and
+                                  prefill_chunk where its deployment sets
+                                  the plan's chunk
     bench/metrics/<metric>.py     a per-layer metric: ``read(rec)``
                                   returns a number, or None when the run
                                   holds nothing to read
-    bench/harness/                the shared yardstick: generator, trace
-                                  reduction, operation and byte counts,
-                                  peak table, reference and control
+    bench/harness/                the shared yardstick: generator, weight
+                                  drawing, served-token check, trace
+                                  reduction, roofline arithmetic, peak
+                                  table
     bench/tests/                  CPU tests of the yardstick
 
 To add a cell, add its ``workloads`` entry (and, where new, a
-``configs`` entry with its file and a traffic file).  To add a per-layer
-metric, add its ``per_layer`` entry and ``bench/metrics/<name>.py``.
-No existing file changes.
+``configs`` entry with its file and a traffic file).  To add a model
+family, add ``bench/families/<family>.py`` and a configuration that names
+it.  To add a per-layer metric, add its ``per_layer`` entry and
+``bench/metrics/<name>.py``.  No existing file changes.
 
 ``--control 1`` (never used by the benchmark's own runs) also reads the
 lower-precision control over the same sample, for setting the limit.
@@ -144,7 +154,10 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     config, mix = cell.config, cell.traffic
     tracer = Tracer(capacity=TRACER_CAPACITY)
-    stack = stack_lib.build(config, args.seed, cell.chips, tracer)
+    max_seq = cell.max_seq_len
+    family = cell.family
+    stack = stack_lib.build(config, family, args.seed, cell.chips, tracer,
+                            max_seq, cell.prefill_chunk)
     rt = stack.runtime
     vocab = int(config["config"]["vocab_size"])
     driver = Driver(stack, mix, args.seed, vocab)
@@ -156,7 +169,6 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
             compiles["window"] += driver.in_window
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
-    max_seq = int(config["max_seq_len"])
     t_build = time.perf_counter()
     driver.compile_warmup(rt.chunk_buckets, max_seq)
     slots = stack_lib.arena_slots(stack)
@@ -194,11 +206,11 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
     events = tracer.events()
     if tracer.dropped:
         raise RuntimeError(f"tracer ring dropped {tracer.dropped} events")
-    dims = stack_lib.dims_of(config, rt.kv_dtype)
+    dims = family.dims(config, rt.kv_dtype)
     kind = devices[0].device_kind
     peaks = peaks_for(kind) if platform != "cpu" else {}
-    rec = RunRecord(cell=cell.name, chips=cell.chips, dims=dims,
-                    peaks=peaks, slots=slots, window=(w0, w1),
+    rec = RunRecord(cell=cell.name, chips=cell.chips, family=family,
+                    dims=dims, peaks=peaks, slots=slots, window=(w0, w1),
                     events=events, svc=stack.arch, reqs=driver.reqs,
                     steps=driver.steps,
                     profile_window=driver.profile_window,
@@ -206,16 +218,15 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
     finished = [r for _, r in driver.results]
     rec.check_emissions(finished)
     sample = check_sample(driver.results, driver.reqs, (w0, w1), args.seed)
-    attempted = sum(1 for r in driver.reqs.values()
-                    if w0 <= r.submit <= w1)
+    attempted = sum(1 for r in driver.reqs.values() if w0 <= r.due <= w1)
     failed = sum(1 for rj in stack.supervisor.report.rejects
                  if rj.req.rid in driver.reqs
-                 and w0 <= driver.reqs[rj.req.rid].submit <= w1)
+                 and w0 <= driver.reqs[rj.req.rid].due <= w1)
     ttft, itl = rec.ttfts(), rec.itls()
     waited = sum(1 for r, q in driver.reqs.items() if w0 <= q.due <= w1
                  and (r not in rec.timelines
                       or rec.timelines[r].first_token is None))
-    out(f"window {w1 - w0:.3f} s: {attempted} requests sent, {failed} "
+    out(f"window {w1 - w0:.3f} s: {attempted} requests due, {failed} "
         f"refused, {len([1 for t, _ in driver.results if w0 <= t <= w1])} "
         f"finished, {rec.tokens_in_window()} tokens, "
         f"{len(driver.steps)} rounds in all; requests waiting for a slot "
@@ -225,6 +236,19 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
         f"without a first token when waiting stopped); gap median "
         f"{percentile(itl, 50)} s, p90 {percentile(itl, 90)} s over "
         f"{len(itl)} gaps")
+    due = [q for q in driver.reqs.values() if w0 <= q.due <= w1]
+    out("ttft in order of arrival (s/prompt tokens): " + ", ".join(
+        f"{t:.3f}/{q.prompt_len}" for q, t in zip(due, ttft)))
+    waits = driver.waits
+    quarter = [[(n, k) for t, n, k in waits
+                if int(4 * (t - w0) / (w1 - w0)) == q] for q in range(4)]
+    mean = lambda xs: f"{sum(xs) / len(xs):.1f}" if xs else "-"
+    out(f"requests waiting for a first token (queued or prefilling) after "
+        f"the window's first round {waits[0][1]}, after its last "
+        f"{waits[-1][1]}; mean after each round, per quarter of the window: "
+        f"requests " + ", ".join(mean([n for n, _ in q]) for q in quarter)
+        + "; their prompt tokens still to prefill " + ", ".join(
+            mean([k for _, k in q]) for q in quarter))
     # what a stall would leave: a quarter with fewer rounds, a long round,
     # a long collection
     rounds = [(a, b - a) for a, b in driver.steps if w0 <= a < w1]
@@ -232,8 +256,14 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
                        if int(4 * (a - w0) / (w1 - w0)) == k)
                    for k in range(4)]
     gc_long = max(driver.gc_pauses, key=lambda p: p[1], default=(0, 0.0, None))
+    a, d = max(rounds, key=lambda r: r[1], default=(0.0, 0.0))
+    inside = sorted(((t1 - t0, f"{pid}/{tid}/{name}")
+                     for kind, pid, tid, name, t0, t1, _ in tracer.events()
+                     if kind == "X" and a <= t0 and t1 <= a + d
+                     and t1 - t0 > 0.05), reverse=True)[:6]
     out(f"rounds per quarter of the window {per_quarter}, longest round "
-        f"{max((d for _, d in rounds), default=0.0)!r} s; garbage "
+        f"{d!r} s (its spans over 50 ms: " + ", ".join(
+            f"{n} {t:.3f} s" for t, n in inside) + f"); garbage "
         f"collections in the window {len(driver.gc_pauses)}, longest "
         f"{gc_long[1]!r} s (generation {gc_long[2]})")
     late = [q.submit - q.due for q in driver.reqs.values()
@@ -249,12 +279,11 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
     driver.stack = None
     del rt
     gc.collect()
-    c = config["config"]
-    ref_kw = dict(width=max_seq, served_max=int(mix["output_len"]["max"]),
-                  eps=float(c["rms_norm_eps"]), theta=float(c["rope_theta"]))
+    ref_kw = dict(width=max_seq, served_max=int(mix["output_len"]["max"]))
     pairs = [(driver.reqs[r.rid].prompt, np.asarray(r.tokens, np.int32))
              for r in sample]
-    gaps = reference.served_gaps(params, dims, pairs, **ref_kw)
+    gaps = reference.served_gaps(params, family.logits, dims, pairs,
+                                 **ref_kw)
     compared = int(sum(g.size for g in gaps))
     worst = float(max((g.max() for g in gaps), default=float("inf")))
     limit = float(config["check"]["worst_gap_sigma"])
@@ -264,8 +293,8 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
         f"requests; worst gap {worst!r} sigma (limit {limit})")
     result = {"correct": correct, "attempted": attempted, "failed": failed}
     if args.control:
-        cg = reference.served_gaps(params, dims, pairs, control=True,
-                                   **ref_kw)
+        cg = reference.served_gaps(params, family.logits, dims, pairs,
+                                   control=True, **ref_kw)
         ctl = float(max((g.max() for g in cg), default=float("inf")))
         out(f"control (fp8) worst gap {ctl!r} sigma over "
             f"{int(sum(g.size for g in cg))} positions")
@@ -292,13 +321,18 @@ def run(cell, args, devices, root: pathlib.Path = ROOT, out=print) -> dict:
             device["window_s"] = p1 - p0
             breakdown = breakdown_of(rec, prof, driver.steps)
     else:
-        e2e = {"ttft_p90_s": percentile(ttft, 90),
+        e2e = {"ttft_p50_s": percentile(ttft, 50),
+               "ttft_p90_s": percentile(ttft, 90),
                "itl_p90_ms": (None if not itl
                               else 1e3 * percentile(itl, 90)),
                "output_tokens_per_s": rec.tokens_in_window() / (w1 - w0),
                "setup_s": setup_s}
-        metrics = {m.name: {"value": e2e[m.name], "unit": m.unit}
-                   for m in cell.end_to_end if e2e.get(m.name) is not None}
+        # ``<name>.<suffix>`` reads what ``<name>`` reads, in the cells
+        # whose spread needs a bound of its own
+        metrics = {m.name: {"value": e2e[m.name.split(".")[0]],
+                            "unit": m.unit}
+                   for m in cell.end_to_end
+                   if e2e.get(m.name.split(".")[0]) is not None}
     result["metrics"] = metrics
     result["device"] = device
     if breakdown is not None:

@@ -34,7 +34,7 @@ OPEN_CELL = {"name": "codeqwen1.5-7b.tp4.chat", "config": "codeqwen1.5-7b",
 def tiny_tree(dst: pathlib.Path, untied: bool = False) -> pathlib.Path:
     """``dst`` gets ``BENCHMARK.json`` and ``bench/`` with every
     configuration cut to a 2-layer, d_model-128 model and every mix to
-    short prompts and answers.  ``untied`` unties every configuration's
+    short prompts and answers, with 256-token slots.  ``untied`` unties every configuration's
     output head: at two layers a tied random model mostly repeats its
     input token, whatever its attention does, so no check can fail it."""
     shutil.copytree(ROOT / "bench", dst / "bench",
@@ -63,6 +63,10 @@ def tiny_tree(dst: pathlib.Path, untied: bool = False) -> pathlib.Path:
         # a full one plus the warm-up tokens), which the reference pads to
         mix["output_len"] = {"median": 6, "sigma": 0.5, "min": 3, "max": 32}
         mix["profile_s"] = 1
+        if "max_seq_len" in mix:
+            mix["max_seq_len"] = 256
+        if "prefill_chunk" in mix:
+            mix["prefill_chunk"] = 64
         if mix["loop"] == "closed":
             mix.update(streams=8, warmup_tokens=2)
         else:

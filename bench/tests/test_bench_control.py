@@ -11,7 +11,8 @@ import pytest
 
 import bench_tree
 
-CELLS = ["minicpm-2b.streams", "codeqwen1.5-7b.tp4.chat"]
+CELLS = ["minicpm-2b.streams", "codeqwen1.5-7b.tp4.chat",
+         "minicpm-2b.prefill"]
 
 
 @pytest.fixture(scope="module")

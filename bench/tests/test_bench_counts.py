@@ -1,19 +1,23 @@
-"""Operation and byte counts per kernel and per step match hand counts
-at minicpm-2b and codeqwen1.5-7b widths."""
+"""Operation and byte counts per kernel and per step (the dense family's,
+``bench/families/dense.py``) match hand counts at minicpm-2b and
+codeqwen1.5-7b widths."""
+import json
 import math
 
 import jax
 import pytest
 
-import bench_tree  # noqa: F401
-from harness import counts, weights
+import bench_tree
+from harness import cells, counts, weights
 
-MINICPM = counts.Dims(layers=40, d_model=2304, heads=36, kv_heads=36,
-                      head_dim=64, d_ff=5760, vocab=122753, tied=True,
-                      qkv_bias=False, kv_dtype="int8")
-CODEQWEN = counts.Dims(layers=32, d_model=4096, heads=32, kv_heads=4,
-                       head_dim=128, d_ff=13440, vocab=92416, tied=False,
-                       qkv_bias=True, kv_dtype="bf16")
+dense = cells.load_family(bench_tree.ROOT, "dense")
+
+MINICPM = dense.Dims(layers=40, d_model=2304, heads=36, kv_heads=36,
+                     head_dim=64, d_ff=5760, vocab=122753, tied=True,
+                     qkv_bias=False, kv_dtype="int8", eps=1e-5, theta=1e4)
+CODEQWEN = dense.Dims(layers=32, d_model=4096, heads=32, kv_heads=4,
+                      head_dim=128, d_ff=13440, vocab=92416, tied=False,
+                      qkv_bias=True, kv_dtype="bf16", eps=1e-5, theta=1e6)
 
 
 def test_minicpm_hand_counts():
@@ -26,12 +30,12 @@ def test_minicpm_hand_counts():
     # weights read once: 40 layers (+2 norms), final norm, tied head
     assert d.weights_read_bytes == 2 * (40 * (61_046_784 + 2 * 2304)
                                         + 2304 + 2304 * 122753)
-    ops, byt = counts.decode_step(d, [100, 300])
+    ops, byt = dense.decode_step(d, [100, 300])
     tok = 2 * (40 * 61_046_784 + 2304 * 122753)
     assert ops == 2 * tok + 4 * 40 * 36 * 64 * (100 + 300)
     assert byt == (d.weights_read_bytes + 40 * 4896 * (99 + 299)
                    + 40 * 4896 * 2 + 2 * 2304 * 2)
-    kops, kbyt = counts.paged_decode_kernel(d, [100, 300])
+    kops, kbyt = dense.paged_decode_kernel(d, [100, 300])
     assert kops == 4 * 40 * 36 * 64 * 400
     assert kbyt == 40 * (4896 * 400 + 2 * 2 * 36 * 64 * 2)
 
@@ -47,12 +51,12 @@ def test_codeqwen_hand_counts():
     # a 64-token chunk after 128 cached tokens: causal keys
     # sum_{i=1..64} (128 + i) = 64*128 + 64*65/2
     keys = 64 * 128 + 64 * 65 // 2
-    ops, byt = counts.chunk_step(d, 128, 64)
+    ops, byt = dense.chunk_step(d, 128, 64)
     assert ops == (2 * 64 * 32 * d.layer_matmul_params + 2 * 4096 * 92416
                    + 4 * 32 * 32 * 128 * keys)
     assert byt == (d.weights_read_bytes + 32 * d.kv_token_bytes * 192
                    + 2 * 4096 * 64)
-    kops, kbyt = counts.paged_chunk_kernel(d, 128, 64)
+    kops, kbyt = dense.paged_chunk_kernel(d, 128, 64)
     assert kops == 4 * 32 * 32 * 128 * keys
     assert kbyt == 32 * (d.kv_token_bytes * 192 + 2 * 64 * 32 * 128 * 2)
 
@@ -61,7 +65,7 @@ def test_codeqwen_hand_counts():
                                                             "codeqwen"])
 def test_weights_read_match_the_weights_drawn(dims):
     n = sum(math.prod(s[0]) for s in jax.tree.leaves(
-        weights.layout(dims), is_leaf=weights._is_spec))
+        dense.layout(dims), is_leaf=weights._is_spec))
     emb = dims.vocab * dims.d_model
     # every weight is read once a step except the untied input table,
     # of which a step gathers only its tokens' rows
@@ -73,3 +77,11 @@ def test_roofline_share_names_its_bound():
     assert pct == pytest.approx(50.0) and bound == "compute"
     pct, bound = counts.roofline_share(1.0, 819e9, 4.0, 197e12, 819e9)
     assert pct == pytest.approx(25.0) and bound == "memory"
+
+
+@pytest.mark.parametrize("name,dims", [("minicpm-2b", MINICPM),
+                                       ("codeqwen1.5-7b", CODEQWEN)])
+def test_configuration_files_give_the_hand_counted_sizes(name, dims):
+    cfg = json.loads((bench_tree.ROOT / "bench" / "configs"
+                      / f"{name}.json").read_text())
+    assert dense.dims(cfg, dims.kv_dtype) == dims

@@ -12,6 +12,7 @@ import bench_tree
 from harness import cells
 
 STREAMS, CHAT = "minicpm-2b.streams", "codeqwen1.5-7b.tp4.chat"
+PREFILL = "minicpm-2b.prefill"
 
 
 @pytest.fixture(scope="module")
@@ -74,7 +75,7 @@ def _exchange_left_out(monkeypatch):
     local("paged_chunk_attention")
 
 
-@pytest.mark.parametrize("workload", [STREAMS, CHAT])
+@pytest.mark.parametrize("workload", [STREAMS, CHAT, PREFILL])
 def test_sound_run_is_correct(tree, workload):
     res = bench_tree.run_cell(tree, workload)
     assert res["correct"], res["checks"]
@@ -88,10 +89,12 @@ def test_sound_run_is_correct(tree, workload):
     (CHAT, _token_altered), (CHAT, _half_batch),
     (CHAT, _state_unchanged), (CHAT, _exchange_left_out),
     (STREAMS, _token_altered), (STREAMS, _half_batch),
-    (STREAMS, _state_unchanged)],
+    (STREAMS, _state_unchanged), (PREFILL, _token_altered),
+    (PREFILL, _half_batch), (PREFILL, _state_unchanged)],
     ids=["token_altered", "half_batch", "state_unchanged",
          "exchange_left_out", "streams-token_altered", "streams-half_batch",
-         "streams-state_unchanged"])
+         "streams-state_unchanged", "prefill-token_altered",
+         "prefill-half_batch", "prefill-state_unchanged"])
 def test_fault_makes_run_incorrect(tree, workload, fault, monkeypatch):
     fault(monkeypatch)
     res = bench_tree.run_cell(tree, workload)

@@ -2,7 +2,8 @@
 the CPU holds: the four readers of ``harness/spans.py`` read, a round's
 host work and device waits add up to its ``step``, the counts the engine
 puts on its ``sample`` and ``prefill_chunk`` spans equal what the record
-reconstructs, and the readers that were there before still read."""
+reconstructs, the readers that were there before still read, and so do
+the prefill cell's."""
 import argparse
 
 import jax
@@ -11,11 +12,13 @@ import pytest
 import bench_tree
 from harness import cells, record
 
-STREAMS = "minicpm-2b.streams"
+STREAMS, PREFILL = "minicpm-2b.streams", "minicpm-2b.prefill"
 NEW = ("host_ms_per_round", "decode_wait_ms", "chunk_wait_ms",
        "supervisor_ms_per_round")
 # the device and peak readers need a TPU's planes in the profile
 HOST_SIDE = ("control_plane_ms_per_round", "batch_occupancy", "arena_slots")
+HOST_SIDE_TTFT = ("chunk_wait_ms.ttft", "decode_wait_ms.ttft",
+                  "host_ms_per_round.ttft", "supervisor_ms_per_round.ttft")
 
 
 @pytest.fixture(scope="module")
@@ -101,3 +104,53 @@ def test_span_counts_equal_the_reconstruction(traced):
                   and e[3] == "prefill_chunk" and w0 <= e[5] <= w1)
     assert ours and ours == sorted(rec.chunk_calls(w0, w1))
 
+
+
+@pytest.fixture(scope="module")
+def traced_prefill(tmp_path_factory):
+    """One traced run of the prefill cell, with the run's record."""
+    import run as bench_run
+    tree = bench_tree.tiny_tree(tmp_path_factory.mktemp("bench"))
+    cell = cells.load_cell(tree, PREFILL)
+    cell.chips = 1
+    recs = []
+    orig = record.RunRecord.__post_init__
+
+    def keep(self):
+        orig(self)
+        recs.append(self)
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(record.RunRecord, "__post_init__", keep)
+    try:
+        args = argparse.Namespace(workload=PREFILL, seed=3_000_000_021,
+                                  seconds=6.0, trace=1, control=0)
+        res = bench_run.run(cell, args, jax.devices()[:1], root=tree,
+                            out=lambda s: None)
+    finally:
+        mp.undo()
+    return tree, cell, res, recs[0]
+
+
+def test_prefill_cell_readers_read(traced_prefill):
+    """The prefill cell's readers of the program's spans and counters
+    read; each ``<name>.ttft`` reads what ``<name>`` reads."""
+    tree, cell, res, rec = traced_prefill
+    got = res["metrics"]
+    for name in HOST_SIDE_TTFT:
+        assert got[name]["value"] is not None, name
+        base = cells.load_reader(tree, name[:-len(".ttft")])
+        assert got[name]["value"] == base(rec)
+    # prompt tokens per round that ran a chunk call, from the engine's own
+    # round spans: no more than a round's chunk budget
+    w0, w1 = rec.window
+    rounds = [e for e in _ring(rec, "engine") if e[3] == "step"
+              and w0 <= e[4] <= w1]
+    chunks = [e for e in rec.events if e[1] == rec.svc
+              and e[3] == "prefill_chunk"]
+    per = [sum(c[6]["tokens"] for c in chunks if r[4] <= c[4] <= r[5])
+           for r in rounds]
+    per = [n for n in per if n]
+    assert per and got["prefill_tokens_per_round"]["value"] == \
+        pytest.approx(sum(per) / len(per))
+    assert max(per) <= 128

@@ -5,7 +5,7 @@ import statistics
 import numpy as np
 import pytest
 
-import bench_tree  # noqa: F401  (puts bench/ and src/ on the path)
+import bench_tree  # puts bench/ and src/ on the path
 from harness import traffic
 
 STREAMS = {"loop": "closed", "streams": 8, "first_prompt_len": 128,
@@ -94,3 +94,56 @@ def test_closed_loop_first_requests_are_under_way():
     # the j-th requests of all streams take the pool's evenly spaced lengths
     assert sorted(r.max_new for r in later) == sorted(
         traffic.quantile_lengths(STREAMS["output_len"], 8).tolist())
+
+
+def _prefill():
+    import json
+    return json.loads((bench_tree.ROOT / "bench" / "traffic"
+                       / "prefill.json").read_text())
+
+
+def test_prefill_mix_schedule_is_fixed_and_clipped():
+    mix = _prefill()
+    a = traffic.OpenLoop(mix, 45)
+    b = traffic.OpenLoop(mix, 45)
+    for tag in ("warmup", "window"):
+        assert [(r.arrival_s, r.prompt_len, r.max_new)
+                for r in a.phases[tag]] == \
+            [(r.arrival_s, r.prompt_len, r.max_new) for r in b.phases[tag]]
+    reqs = a.phases["warmup"] + a.phases["window"]
+    p, o = mix["prompt_len"], mix["output_len"]
+    assert all(p["min"] <= r.prompt_len <= p["max"] for r in reqs)
+    assert all(o["min"] <= r.max_new <= o["max"] for r in reqs)
+    assert len(a.phases["window"]) == round(mix["rate_per_s"] * 45)
+    # the longest request fits the mix's slot
+    assert p["max"] + o["max"] <= mix["max_seq_len"]
+
+
+@pytest.mark.parametrize("workload,slot,chunk",
+                         [("minicpm-2b.prefill", 2048, 512),
+                          ("minicpm-2b.streams", 1024, None)])
+def test_slot_length_reaches_the_stack(workload, slot, chunk, monkeypatch):
+    """The mix's ``max_seq_len`` overrides the configuration's, and its
+    ``prefill_chunk`` the category's chunk; the stack is built with both
+    (the compile warm-up and the reference's width read the same
+    ``Cell.max_seq_len``)."""
+    import argparse
+    import jax
+    import run as bench_run
+    from harness import cells, stack
+
+    class Built(Exception):
+        pass
+
+    def build(config, family, seed, chips, tracer, max_seq_len,
+              prefill_chunk=None):
+        raise Built(max_seq_len, prefill_chunk)
+
+    monkeypatch.setattr(stack, "build", build)
+    cell = cells.load_cell(bench_tree.ROOT, workload)
+    assert (cell.max_seq_len, cell.prefill_chunk) == (slot, chunk)
+    args = argparse.Namespace(workload=workload, seed=BIG_SEED, seconds=1.0,
+                              trace=0, control=0)
+    with pytest.raises(Built) as got:
+        bench_run.run(cell, args, jax.devices()[:1], out=lambda s: None)
+    assert got.value.args == (slot, chunk)
